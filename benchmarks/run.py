@@ -65,6 +65,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--out", default="results/bench_summary.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = args.only.split(",") if args.only else SUITES
     t0 = time.perf_counter()
     summaries, failures = run_suite(names, quick=not args.full)

@@ -68,7 +68,9 @@ def main() -> None:
     if not args.validate_only:
         sys.path.insert(0, str(REPO))  # import benchmarks.* from anywhere
         from benchmarks.run import run_suite  # the one orchestration path
+        from repro.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         summaries, failures = run_suite(names, quick=args.smoke)
         # one cross-suite roll-up with per-suite wall time (_wall_s) so
         # CI runs leave a perf trajectory, not just pass/fail artifacts

@@ -452,7 +452,7 @@ class JobDAG:
         return np.array([op == "sum" for op in self.compose])
 
     def _compose_pair(self, a, b, relation: str, use_kernel: bool,
-                      kernel_interpret: bool, chunk: int):
+                      kernel_interpret: bool | None, chunk: int):
         """Compose two partial frontiers ``(F, X_full)`` and Pareto
         re-filter through the FrontierStore incremental dominance pass."""
         (Fa, Xa), (Fb, Xb) = a, b
@@ -482,7 +482,7 @@ class JobDAG:
         return store.frontier()
 
     def compose_frontiers(self, frontiers: dict, use_kernel: bool = False,
-                          kernel_interpret: bool = True,
+                          kernel_interpret: bool | None = None,
                           chunk: int = 4096,
                           max_combos: int = 200_000) -> ComposedFrontier:
         """Combine per-stage Pareto frontiers into the job frontier.
@@ -633,7 +633,7 @@ def solve_dag(
     grid_l: int = 2,
     batch_rects: int = 4,
     use_kernel: bool = False,
-    kernel_interpret: bool = True,
+    kernel_interpret: bool | None = None,
     max_rounds: int = 10_000,
     deadline_s: float | None = None,
 ) -> DAGResult:

@@ -69,7 +69,8 @@ class FrontierStore:
     """Grow-on-demand array store with a live incremental Pareto mask."""
 
     def __init__(self, k: int, dim: int, capacity: int = 256,
-                 use_kernel: bool = False, kernel_interpret: bool = True,
+                 use_kernel: bool = False,
+                 kernel_interpret: bool | None = None,
                  bounds: np.ndarray | None = None, bounds_tol: float = 1e-6):
         cap = _bucket(capacity, floor=64)
         self.k = int(k)
@@ -132,7 +133,7 @@ class FrontierStore:
 
     @classmethod
     def from_state(cls, arrays: dict, meta: dict, use_kernel: bool = False,
-                   kernel_interpret: bool = True) -> "FrontierStore":
+                   kernel_interpret: bool | None = None) -> "FrontierStore":
         """Rebuild a store from :meth:`state_dict` output.
 
         Kernel routing (``use_kernel``) follows the *restoring* process's

@@ -159,7 +159,7 @@ def export_pf_state(state: PFState) -> tuple[dict, dict]:
 
 
 def import_pf_state(arrays: dict, meta: dict, use_kernel: bool = False,
-                    kernel_interpret: bool = True) -> PFState:
+                    kernel_interpret: bool | None = None) -> PFState:
     """Inverse of :func:`export_pf_state` — rebuild a resumable state.
 
     Kernel flags follow the restoring engine's configuration (see
@@ -227,7 +227,7 @@ class ProgressiveFrontier:
         target: int = 0,
         solver: MOGDSolver | None = None,
         use_kernel: bool = False,
-        kernel_interpret: bool = True,
+        kernel_interpret: bool | None = None,
     ):
         if mode not in ("S", "AS", "AP"):
             raise ValueError(f"unknown PF mode {mode!r}")
@@ -240,7 +240,8 @@ class ProgressiveFrontier:
         self.batch_rects = batch_rects
         self.target = target
         # route the store's dominance pass through the Pallas kernel
-        # (interpret=False on real TPU); default is the dense jnp pass
+        # (kernel_interpret=None: compiled on TPU, interpreted on CPU);
+        # default is the dense jnp pass
         self.use_kernel = use_kernel
         self.kernel_interpret = kernel_interpret
         # An injected solver lets the service layer share one compiled MOGD

@@ -51,6 +51,25 @@ import numpy as np
 
 Array = jax.Array
 
+# Every MOGD program the executor builds runs its matmuls in full f32.  On
+# a TPU the default for f32 operands is one bf16 pass: over 80 projected-
+# Adam steps that sends descents from the same start to points tenths
+# apart (TPU v5e), so results would depend on the implementation and the
+# fused kernel's parity gate could not pass.  The fused kernel sets the
+# same precision on its own dots (``kernels.mogd_descend.HIGHEST``).
+MATMUL_PRECISION = "highest"
+
+
+def _f32_matmuls(fn: Callable) -> Callable:
+    """``fn`` traced with every matmul at :data:`MATMUL_PRECISION` (the
+    precision is fixed into each dot as it is traced)."""
+
+    def traced(*args):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return fn(*args)
+
+    return traced
+
 
 # ---------------------------------------------------------------------------
 # Math primitives (paper Eq. 4 + §4.2.1 projected descent).  Moved here from
@@ -386,6 +405,9 @@ class ProbeExecutor:
         self._c_padded_rows = m.counter("exec.padded_rows", self._labels)
         self.last_bucket: tuple | None = None
         self.last_fill: float = 1.0
+        # devices the last dispatch's results live on: 1 unsharded, the
+        # mesh size when the bucket was sharded
+        self.last_devices: int = 0
 
     # legacy int counter surface: views over the registry ------------------
     @property
@@ -458,6 +480,7 @@ class ProbeExecutor:
             "fill_ratio": (self.useful_rows / self.padded_rows
                            if self.padded_rows else 1.0),
             "last_bucket": self.last_bucket,
+            "last_devices": self.last_devices,
             "dispatch_origins": dict(self.dispatch_origins),
         }
 
@@ -574,54 +597,55 @@ class ProbeExecutor:
     def _parity_check(self, req: ProbeRequest, plan) -> bool:
         """One-time per-structure numeric gate: fused descend must match
         the scan path's end state on a tiny slice of the real request
-        before the structure commits to the fused backend."""
+        before the structure commits to the fused backend.
+
+        A numeric mismatch is the only reason to fall back.  A kernel
+        that fails to lower or compile raises out of the executor: the
+        scan path must never hide a broken fused tier."""
         from repro.kernels.mogd_descend import descend_batch
 
-        try:
-            cfg = req.cfg
-            x0 = jnp.asarray(req.x0s, jnp.float32)[:1, :2]  # (1, S', D)
-            lo = jnp.asarray(req.los, jnp.float32)[:1]
-            hi = jnp.asarray(req.his, jnp.float32)[:1]
-            k = lo.shape[-1]
-            if req.bounds is not None:
-                ulo, uhi, uscale = (jnp.asarray(b, jnp.float32)[:1]
-                                    for b in req.bounds)
-            else:
-                ulo = jnp.full((1, k), -jnp.inf)
-                uhi = jnp.full((1, k), jnp.inf)
-                uscale = jnp.ones((1, k))
-            target = jnp.asarray(req.targets, jnp.int32).reshape(-1)[:1]
-            if req.params_b is None:
-                params = req.program.params
-                params_g = jax.tree.map(
-                    lambda a: jnp.asarray(a)[None], params)
-            else:
-                params_g = jax.tree.map(
-                    lambda a: jnp.asarray(a)[:1], req.params_b)
-                params = jax.tree.map(lambda a: a[0], params_g)
+        cfg = req.cfg
+        x0 = jnp.asarray(req.x0s, jnp.float32)[:1, :2]  # (1, S', D)
+        lo = jnp.asarray(req.los, jnp.float32)[:1]
+        hi = jnp.asarray(req.his, jnp.float32)[:1]
+        k = lo.shape[-1]
+        if req.bounds is not None:
+            ulo, uhi, uscale = (jnp.asarray(b, jnp.float32)[:1]
+                                for b in req.bounds)
+        else:
+            ulo = jnp.full((1, k), -jnp.inf)
+            uhi = jnp.full((1, k), jnp.inf)
+            uscale = jnp.ones((1, k))
+        target = jnp.asarray(req.targets, jnp.int32).reshape(-1)[:1]
+        if req.params_b is None:
+            params = req.program.params
+            params_g = jax.tree.map(lambda a: jnp.asarray(a)[None], params)
+        else:
+            params_g = jax.tree.map(lambda a: jnp.asarray(a)[:1],
+                                    req.params_b)
+            params = jax.tree.map(lambda a: a[0], params_g)
 
-            apply = req.program.apply
-            penalty, tie_eps = cfg.penalty, cfg.tie_break_eps
+        apply = req.program.apply
+        penalty, tie_eps = cfg.penalty, cfg.tie_break_eps
 
-            def loss_fn(x):
-                f = apply(params, x)
-                excess = (jnp.maximum(ulo[0] - f, 0.0)
-                          + jnp.maximum(f - uhi[0], 0.0))
-                bound = jnp.where(
-                    excess > 0.0, (excess / uscale[0]) ** 2 + penalty, 0.0
-                ).sum()
-                return _eq4_loss(f, lo[0], hi[0], target[0], penalty,
-                                 tie_eps) + bound
+        def loss_fn(x):
+            f = apply(params, x)
+            excess = (jnp.maximum(ulo[0] - f, 0.0)
+                      + jnp.maximum(f - uhi[0], 0.0))
+            bound = jnp.where(
+                excess > 0.0, (excess / uscale[0]) ** 2 + penalty, 0.0
+            ).sum()
+            return _eq4_loss(f, lo[0], hi[0], target[0], penalty,
+                             tie_eps) + bound
 
+        with jax.default_matmul_precision(MATMUL_PRECISION):
             want = jax.vmap(
                 lambda x0_: adam_project_descend(loss_fn, x0_, cfg))(x0[0])
-            got = descend_batch(
-                plan, cfg, params_g, x0[:, None], lo[:, None], hi[:, None],
-                ulo[:, None], uhi[:, None], uscale[:, None], target[:, None],
-            )[0, 0]
-            return bool(jnp.max(jnp.abs(got - want)) <= 1e-3)
-        except Exception:  # noqa: BLE001 — any failure means "not fusable"
-            return False
+        got = descend_batch(
+            plan, cfg, params_g, x0[:, None], lo[:, None], hi[:, None],
+            ulo[:, None], uhi[:, None], uscale[:, None], target[:, None],
+        )[0, 0]
+        return bool(jnp.max(jnp.abs(got - want)) <= 1e-3)
 
     # -- compilation -------------------------------------------------------
     def _build(self, req: ProbeRequest, Gp: int, Rp: int, skey: tuple,
@@ -727,26 +751,25 @@ class ProbeExecutor:
 
         n = self._mesh_div()
         if n > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             if axis == "group" and Gp % n == 0:
                 # shard the group axis: params and rows partition together
                 spec = P(self.mesh_axis)
-                batched = shard_map(batched, mesh=self.mesh,
-                                    in_specs=spec, out_specs=spec,
-                                    check_rep=False)
+                batched = jax.shard_map(batched, mesh=self.mesh,
+                                        in_specs=spec, out_specs=spec,
+                                        check_vma=False)
             elif axis == "row" and Rp % n == 0:
                 # groups replicated, rows sharded (params fully replicated)
                 row_spec = P(None, self.mesh_axis)
-                batched = shard_map(
+                batched = jax.shard_map(
                     batched, mesh=self.mesh,
                     in_specs=(P(), *([row_spec] * N_ROW_FIELDS)),
-                    out_specs=row_spec, check_rep=False)
+                    out_specs=row_spec, check_vma=False)
             # else: indivisible bucket — unsharded fallback, never fail
         self.compile_counts[skey] = self.compile_counts.get(skey, 0) + 1
         self._c_compiles.inc()
-        return jax.jit(batched)
+        return jax.jit(_f32_matmuls(batched))
 
     # -- assembly ----------------------------------------------------------
     @staticmethod
@@ -877,6 +900,7 @@ class ProbeExecutor:
             self._c_padded_rows.inc(Gp * Rp)
             self.last_bucket = (Gp, Rp)
             self.last_fill = useful / (Gp * Rp)
+            self.last_devices = len(x.sharding.device_set)
             if origin is not None:
                 self.obs.metrics.counter(
                     "exec.dispatches_by_origin",
@@ -902,7 +926,8 @@ class ProbeExecutor:
             fn = self._evals.pop(key, None)  # re-insert as newest (LRU)
             if fn is None:
                 apply = program.apply
-                fn = jax.jit(jax.vmap(apply, in_axes=(None, 0)))
+                fn = jax.jit(
+                    _f32_matmuls(jax.vmap(apply, in_axes=(None, 0))))
                 self._c_eval_compiles.inc()
             self._evals[key] = fn
             while len(self._evals) > self.max_programs:

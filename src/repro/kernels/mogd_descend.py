@@ -41,10 +41,15 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .platform import default_interpret, resolve_interpret
 
 BLOCK_M = 256
+# Full-f32 matmuls, as the executor's scan path runs them: at the TPU
+# default (one bf16 pass) 80 Adam steps of two correct implementations end
+# tenths apart, and the parity gate cannot tell a broken kernel from noise.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -119,7 +124,11 @@ def fold_affine(plan: DescendPlan, params):
         ws = [jnp.asarray(l["w"]) for l in p["layers"]]
         bs = [jnp.asarray(l["b"]) for l in p["layers"]]
         xm, xs = jnp.asarray(p["x_mean"]), jnp.asarray(p["x_std"])
-        ym, ys = jnp.asarray(p["y_mean"]), jnp.asarray(p["y_std"])
+        # target moments are one scalar per group, stored as () or (1,)
+        # (a trained regressor keeps its (1,) column moments)
+        lead = ws[0].shape[:-2]
+        ym = jnp.asarray(p["y_mean"]).reshape(lead)
+        ys = jnp.asarray(p["y_std"]).reshape(lead)
         bs[0] = bs[0] - jnp.einsum("...d,...dh->...h", xm / xs, ws[0])
         ws[0] = ws[0] / xs[..., :, None]
         ws[-1] = ws[-1] * ys[..., None, None]
@@ -170,8 +179,9 @@ def _grad_rows(plan: DescendPlan, tie_eps, wbs, x, lo, hi, ulo, uhi, us,
         h = x
         acts = []
         for l in range(n_layers):
-            a = jnp.dot(h, ws[l], preferred_element_type=jnp.float32)
-            a = a + bs[l][None, :]
+            a = jnp.dot(h, ws[l], precision=HIGHEST,
+                        preferred_element_type=jnp.float32)
+            a = a + bs[l]  # (H,) in the XLA tier, (1, H) in the kernel
             if l < n_layers - 1:
                 acts.append(a)
                 h = jnp.maximum(a, 0.0)
@@ -188,23 +198,36 @@ def _grad_rows(plan: DescendPlan, tie_eps, wbs, x, lo, hi, ulo, uhi, us,
                          us[:, j], tsel[:, j], tie_eps)
         g = (dldf * dfdraw)[:, None]  # (M, 1)
         for l in range(n_layers - 1, -1, -1):
-            g = jnp.dot(g, ws[l].T, preferred_element_type=jnp.float32)
+            g = jnp.dot(g, ws[l].T, precision=HIGHEST,
+                        preferred_element_type=jnp.float32)
             if l > 0:
                 g = g * (acts[l - 1] > 0.0)
         dx = dx + g
     return jnp.where(jnp.isfinite(dx), dx, 0.0)
 
 
-def _adam_update(x, m, v, g, t, cfg):
-    """One projected-Adam step at (1-based, traced) step index ``t`` —
-    bit-for-bit the update of ``adam_project_descend``."""
-    m = cfg.adam_b1 * m + (1 - cfg.adam_b1) * g
-    v = cfg.adam_b2 * v + (1 - cfg.adam_b2) * g * g
-    mh = m / (1 - jnp.power(cfg.adam_b1, t))
-    vh = v / (1 - jnp.power(cfg.adam_b2, t))
+def adam_schedule(cfg):
+    """``(steps, 3)`` per-step scalars of the descent: the cosine-decayed
+    learning rate and the Adam bias-correction denominators ``1 - b1**t``,
+    ``1 - b2**t`` (t = 1..steps).  Computed once in XLA with the scan
+    path's own expressions, so both tiers divide by the very values
+    ``adam_project_descend`` does, and the kernel evaluates no
+    transcendental (Mosaic cannot lower ``powf`` with a traced
+    exponent)."""
+    t = jnp.arange(1, cfg.steps + 1, dtype=jnp.float32)
     frac = (t - 1.0) / cfg.steps
     lr = cfg.lr * (cfg.lr_floor
                    + (1 - cfg.lr_floor) * 0.5 * (1 + jnp.cos(jnp.pi * frac)))
+    return jnp.stack([lr, 1 - cfg.adam_b1 ** t, 1 - cfg.adam_b2 ** t], 1)
+
+
+def _adam_update(x, m, v, g, lr, d1, d2, cfg):
+    """One projected-Adam step — the update of ``adam_project_descend``,
+    with one row of :func:`adam_schedule` as ``(lr, d1, d2)``."""
+    m = cfg.adam_b1 * m + (1 - cfg.adam_b1) * g
+    v = cfg.adam_b2 * v + (1 - cfg.adam_b2) * g * g
+    mh = m / d1
+    vh = v / d2
     x = jnp.clip(x - lr * mh / (jnp.sqrt(vh) + cfg.adam_eps), 0.0, 1.0)
     return x, m, v
 
@@ -219,15 +242,13 @@ def _descend_rows_xla(plan: DescendPlan, cfg, wbs, x0, lo, hi, ulo, uhi, us,
     """One group's rows, hand-written backward, ``lax.scan`` over steps."""
     tie_eps = cfg.tie_break_eps
 
-    def step(carry, _):
-        x, m, v, t = carry
+    def step(carry, sched):
+        x, m, v = carry
         g = _grad_rows(plan, tie_eps, wbs, x, lo, hi, ulo, uhi, us, tsel)
-        x, m, v = _adam_update(x, m, v, g, t, cfg)
-        return (x, m, v, t + 1.0), None
+        return _adam_update(x, m, v, g, *sched, cfg), None
 
     z = jnp.zeros_like(x0)
-    (x, _, _, _), _ = jax.lax.scan(
-        step, (x0, z, z, jnp.float32(1.0)), None, length=cfg.steps)
+    (x, _, _), _ = jax.lax.scan(step, (x0, z, z), adam_schedule(cfg))
     return x
 
 
@@ -240,11 +261,12 @@ def _make_kernel(plan: DescendPlan, cfg, block_m: int):
     tie_eps = cfg.tie_break_eps
     n_wb = sum(len(d) - 1 for d in plan.layer_dims) * 2
 
-    def kernel(x0_ref, lo_ref, hi_ref, ulo_ref, uhi_ref, us_ref, tsel_ref,
-               *rest):
+    def kernel(sched_ref, x0_ref, lo_ref, hi_ref, ulo_ref, uhi_ref, us_ref,
+               tsel_ref, *rest):
         out_ref = rest[n_wb]
         # Rebuild the per-objective (ws, bs) weight lists from the flat
         # variadic refs — loaded once per grid step, resident thereafter.
+        # Biases arrive as (1, H) rows of a (G, 1, H) array.
         wbs, i = [], 0
         for dims in plan.layer_dims:
             ws, bs = [], []
@@ -261,8 +283,8 @@ def _make_kernel(plan: DescendPlan, cfg, block_m: int):
         def body(i, carry):
             x, m, v = carry
             g = _grad_rows(plan, tie_eps, wbs, x, lo, hi, ulo, uhi, us, tsel)
-            x, m, v = _adam_update(x, m, v, g, i + 1.0, cfg)
-            return x, m, v
+            lr, d1, d2 = (sched_ref[3 * i + c] for c in range(3))
+            return _adam_update(x, m, v, g, lr, d1, d2, cfg)
 
         z = jnp.zeros_like(x0)
         x, _, _ = jax.lax.fori_loop(0, cfg.steps, body, (x0, z, z))
@@ -290,14 +312,19 @@ def _descend_pallas(plan: DescendPlan, cfg, folded, x, lo, hi, ulo, uhi, us,
     grid = (G, Mp // block_m)
 
     row_spec = lambda w: pl.BlockSpec((1, block_m, w), lambda g, t: (g, t, 0))
-    in_specs = [row_spec(D)] + [row_spec(k)] * 6
-    args = [x, lo, hi, ulo, uhi, us, tsel]
+    # the schedule rides flat in SMEM: scalars read by step index
+    in_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM), row_spec(D)]
+                + [row_spec(k)] * 6)
+    args = [adam_schedule(cfg).reshape(-1), x, lo, hi, ulo, uhi, us, tsel]
     for ws, bs in folded:
         for w, b in zip(ws, bs):
+            # (G, H) biases ride as (G, 1, H): a (1, H) block of a (G, H)
+            # array breaks the TPU (8, 128) block rule
+            b = b[:, None, :]
             in_specs.append(
                 pl.BlockSpec((1, *w.shape[1:]), lambda g, t: (g, 0, 0)))
             in_specs.append(
-                pl.BlockSpec((1, b.shape[-1]), lambda g, t: (g, 0)))
+                pl.BlockSpec((1, *b.shape[1:]), lambda g, t: (g, 0, 0)))
             args.extend([w, b])
 
     out = pl.pallas_call(

@@ -147,7 +147,7 @@ class MOOService:
         max_sessions: int = 256,
         max_cached_tasks: int = 512,
         use_kernel: bool = False,
-        kernel_interpret: bool = True,
+        kernel_interpret: bool | None = None,
         executor: ProbeExecutor | None = None,
         mesh="auto",
         structure_coalescing: bool = True,

@@ -7,9 +7,11 @@ so the main test process keeps seeing 1 device.
 """
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -65,10 +67,11 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import AxisType
 
     out = {}
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     # --- 1. compressed all-reduce under shard_map ---------------------
     from repro.distributed import compressed_psum
@@ -80,9 +83,9 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
         return m[None], r[None]
 
     spec = P("data", None)
-    mean, resid = jax.jit(shard_map(
+    mean, resid = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(spec, spec),
-        out_specs=(spec, spec)))(g, res)
+        out_specs=(spec, spec), check_vma=False))(g, res)
     true_mean = jnp.broadcast_to(g.mean(0, keepdims=True), g.shape)
     err = float(jnp.abs(mean - true_mean).max())
     scale = float(jnp.abs(g).max())
@@ -131,8 +134,8 @@ def subprocess_results():
     proc = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=Path(__file__).resolve().parents[1])
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines()
             if l.startswith("RESULT::")][0]

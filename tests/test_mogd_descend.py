@@ -227,6 +227,43 @@ class TestExecutorBackendSeam:
         assert s["fused_fallbacks"] == 1 and s["fused_dispatches"] == 0
         assert r.x.shape[0] == 3  # still solved, on the scan path
 
+    def test_parity_gate_raises_kernel_errors(self, monkeypatch):
+        # a fused kernel that fails to lower or compile must surface, not
+        # quietly send its structure to the scan path
+        import repro.kernels.mogd_descend as md
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setattr(md, "descend_batch", broken)
+        task = mlp_surrogate_task(seed=4, d=3, arch=(8, 8), k=2)
+        ex = ProbeExecutor(mesh=None, backend="auto")
+        solver = MOGDSolver(task.compile(), MOGDConfig(steps=20, multistart=2),
+                            executor=ex)
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            solver.solve(self._boxes(task.compile(), 3))
+        assert ex.stats()["fused_fallbacks"] == 0
+
+    def test_parity_gate_mismatch_falls_back_once(self, monkeypatch):
+        # the real gate, fed a kernel whose end states are off by 0.5:
+        # the structure falls back to scan and is counted exactly once
+        import repro.kernels.mogd_descend as md
+
+        real = md.descend_batch
+        monkeypatch.setattr(md, "descend_batch",
+                            lambda *a, **kw: real(*a, **kw) + 0.5)
+        task = mlp_surrogate_task(seed=4, d=3, arch=(8, 8), k=2)
+        ex = ProbeExecutor(mesh=None, backend="auto")
+        solver = MOGDSolver(task.compile(), MOGDConfig(steps=20, multistart=2),
+                            executor=ex)
+        boxes = self._boxes(task.compile(), 3)
+        solver.solve(boxes)
+        r = solver.solve(boxes)
+        s = ex.stats()
+        assert s["fused_fallbacks"] == 1 and s["fused_dispatches"] == 0
+        assert s["fused_structures"] == 0
+        assert r.x.shape[0] == 3  # still solved, on the scan path
+
     def test_grouped_tenants_share_fused_program(self):
         # two same-architecture tenants: one structure, one fused dispatch
         cfg = MOGDConfig(steps=20, multistart=2)

@@ -2,8 +2,10 @@
 serving engine, and a real (subprocess, 512-device) dry-run cell."""
 
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -129,8 +131,8 @@ class TestDryRunIntegration:
              "--arch", "rwkv6-3b", "--shape", "decode_32k",
              "--out", str(tmp_path)],
             capture_output=True, text=True, timeout=900,
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                 "HOME": "/root"}, cwd="/root/repo")
+            env={**os.environ, "PYTHONPATH": "src"},
+            cwd=Path(__file__).resolve().parents[1])
         assert proc.returncode == 0, proc.stderr[-2000:]
         arts = list(tmp_path.glob("*.json"))
         assert len(arts) == 1

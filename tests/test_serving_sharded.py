@@ -3,9 +3,11 @@ multi-device mesh must reproduce single-device logits (subprocess with 8
 host devices; the main process keeps 1)."""
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -15,7 +17,7 @@ _SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.configs import get_smoke
     from repro.distributed import ShardingRules, named_sharding_tree
@@ -34,7 +36,8 @@ _SCRIPT = textwrap.dedent("""
                             {"tokens": toks[:, -1:]}, jnp.int32(S))
 
     # --- sharded: data=2 x model=4, cache seq-sharded over model ----
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = ShardingRules(mesh)
     p_sh = named_sharding_tree(rules, params, axes)
     params_s = jax.tree.map(jax.device_put, params, p_sh)
@@ -62,8 +65,8 @@ def results():
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=Path(__file__).resolve().parents[1])
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT::")]
     return json.loads(line[0][len("RESULT::"):])
