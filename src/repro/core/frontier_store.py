@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import NOOP_SPAN
+
 Array = jax.Array
 
 
@@ -230,9 +232,11 @@ class FrontierStore:
         return keep, self._alive & ~killed
 
     # ------------------------------------------------------------------
-    def add(self, F_new, X_new) -> int:
+    def add(self, F_new, X_new, span=NOOP_SPAN) -> int:
         """Offer a batch of candidate points; returns how many entered the
-        frontier.  ``F_new: (B, k)``, ``X_new: (B, D)`` (or single rows)."""
+        frontier.  ``F_new: (B, k)``, ``X_new: (B, D)`` (or single rows).
+        ``span`` parents the ``store.dominance`` span around the dominance
+        pass and its read-back (DESIGN.md §14)."""
         F_new = np.atleast_2d(np.asarray(F_new, dtype=np.float64))
         X_new = np.atleast_2d(np.asarray(X_new, dtype=np.float64))
         if F_new.shape[0] != X_new.shape[0]:
@@ -277,14 +281,16 @@ class FrontierStore:
         Bp[: len(Fb)] = Fb
         bvalid = np.zeros(bb, dtype=bool)
         bvalid[: len(Fb)] = True
-        if self.use_kernel:
-            keep, still_alive = self._kernel_pass(Bp, bvalid)
-        else:
-            keep, still_alive = _incremental_pass(
-                jnp.asarray(self._F), jnp.asarray(self._alive),
-                jnp.asarray(Bp), jnp.asarray(bvalid))
-        keep = np.asarray(keep)[: len(Fb)]
-        still_alive = np.asarray(still_alive).copy()
+        with span.child("store.dominance", cat="store",
+                        args={"offers": len(Fb), "kernel": self.use_kernel}):
+            if self.use_kernel:
+                keep, still_alive = self._kernel_pass(Bp, bvalid)
+            else:
+                keep, still_alive = _incremental_pass(
+                    jnp.asarray(self._F), jnp.asarray(self._alive),
+                    jnp.asarray(Bp), jnp.asarray(bvalid))
+            keep = np.asarray(keep)[: len(Fb)]
+            still_alive = np.asarray(still_alive).copy()
         for r in np.nonzero(self._alive & ~still_alive)[0]:
             self._keys.discard(self._row_keys[r])  # retired rows free keys
         self._alive = still_alive

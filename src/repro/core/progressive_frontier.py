@@ -31,6 +31,8 @@ import time
 
 import numpy as np
 
+from repro.obs import NOOP_SPAN
+
 from .frontier_store import FrontierStore
 from .hyperrectangle import (
     Rectangle,
@@ -378,26 +380,29 @@ class ProgressiveFrontier:
         return cells, boxes, info
 
     def absorb(self, state: PFState, cells: list[Rectangle], res: COResult,
-               pop: PopInfo | None = None) -> None:
+               pop: PopInfo | None = None, span=NOOP_SPAN) -> None:
         """Fold one batched probe result back into the state: push the
         uncertain sub-rectangles and offer all feasible points to the
         frontier store in a single incremental dominance pass.  ``pop``
         (the matching ``prepare_parallel`` metadata, when available)
-        attributes the popped volume to the gain-log row."""
-        state.probes += len(cells)
-        fs, xs = [], []
-        for c, ok, f, x in zip(cells, res.feasible, res.f, res.x):
-            if not bool(ok):
-                continue  # cell has no Pareto candidate -> omitted (§4.3)
-            fm = np.clip(f, c.utopia, c.nadir)
-            fs.append(fm)
-            xs.append(x)
-            for sub in split_rectangle(c.utopia, fm, c.nadir):
-                state.queue.push(sub)
-        if fs:
-            state.store.add(np.stack(fs), np.stack(xs))
-        state.record_gain(pop.popped_volume if pop is not None else 0.0,
-                          len(cells))
+        attributes the popped volume to the gain-log row.  ``span``
+        parents the ``pf.absorb`` span (DESIGN.md §14)."""
+        with span.child("pf.absorb", cat="pf",
+                        args={"cells": len(cells)}) as sp:
+            state.probes += len(cells)
+            fs, xs = [], []
+            for c, ok, f, x in zip(cells, res.feasible, res.f, res.x):
+                if not bool(ok):
+                    continue  # cell has no Pareto candidate -> omitted (§4.3)
+                fm = np.clip(f, c.utopia, c.nadir)
+                fs.append(fm)
+                xs.append(x)
+                for sub in split_rectangle(c.utopia, fm, c.nadir):
+                    state.queue.push(sub)
+            if fs:
+                state.store.add(np.stack(fs), np.stack(xs), span=sp)
+            state.record_gain(pop.popped_volume if pop is not None else 0.0,
+                              len(cells))
 
     def restore(self, state: PFState, cells: list[Rectangle]) -> None:
         """Return prepared-but-unsolved cells to the queue (a failed probe

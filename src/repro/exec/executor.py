@@ -296,6 +296,43 @@ class ProbeRequest:
     use_std: bool = False
 
 
+class _Moves:
+    """Counts the arrays one dispatch moves between host and device
+    (``exec.transfers`` / ``exec.transfer_bytes``): host data handed to
+    the device, and device arrays read back to the host."""
+
+    __slots__ = ("count", "bytes")
+
+    def __init__(self):
+        self.count = 0
+        self.bytes = 0
+
+    def _add(self, a) -> None:
+        # host data crosses at its canonical dtype (float64 as float32)
+        self.count += 1
+        self.bytes += int(np.size(a)) * jax.dtypes.canonicalize_dtype(
+            a.dtype).itemsize
+
+    def to_device(self, a, dtype=None):
+        """``jnp.asarray(a, dtype)``, counted when ``a`` is host data."""
+        if not isinstance(a, jax.Array):
+            a = np.asarray(a, dtype)
+            self._add(a)
+        return jnp.asarray(a, dtype)
+
+    def to_host(self, a) -> np.ndarray:
+        """``np.asarray(a)``, counted when ``a`` is a device array."""
+        if isinstance(a, jax.Array):
+            self._add(a)
+        return np.asarray(a)
+
+    def launch(self, args) -> None:
+        """Count the host leaves a jitted call will copy to the device."""
+        for a in jax.tree.leaves(args):
+            if not isinstance(a, jax.Array):
+                self._add(a)
+
+
 # ---------------------------------------------------------------------------
 # The executor
 # ---------------------------------------------------------------------------
@@ -380,13 +417,17 @@ class ProbeExecutor:
         self.obs = obs if obs is not None else Observability()
         m = self.obs.metrics
         self._labels = {"executor": f"ex{next(_exec_ids)}"}
-        self._c_compiles = m.counter(
-            "exec.compiles", self._labels,
-            help="solve-program jit builds (all structures and buckets)")
-        self._c_eval_compiles = m.counter(
-            "exec.eval_compiles", self._labels)
         self._c_dispatches = m.counter(
             "exec.dispatches", self._labels, help="device dispatches")
+        self._c_requests = m.counter(
+            "exec.requests", self._labels,
+            help="coalesced requests (tenant spans) dispatched")
+        self._c_transfers = m.counter(
+            "exec.transfers", self._labels,
+            help="arrays moved between host and device by dispatches")
+        self._c_transfer_bytes = m.counter(
+            "exec.transfer_bytes", self._labels,
+            help="bytes of the arrays exec.transfers counts")
         self._c_probes = m.counter(
             "exec.probes", self._labels, help="useful probe rows solved")
         self._c_fused_dispatches = m.counter(
@@ -410,10 +451,6 @@ class ProbeExecutor:
         self.last_devices: int = 0
 
     # legacy int counter surface: views over the registry ------------------
-    @property
-    def eval_compiles(self) -> int:
-        return int(self._c_eval_compiles.value)
-
     @property
     def dispatches(self) -> int:
         return int(self._c_dispatches.value)
@@ -467,8 +504,10 @@ class ProbeExecutor:
         return {
             "structures": self.structures_compiled,
             "compiles": self.total_compiles,
-            "eval_compiles": self.eval_compiles,
             "dispatches": self.dispatches,
+            "requests": int(self._c_requests.value),
+            "transfers": int(self._c_transfers.value),
+            "transfer_bytes": int(self._c_transfer_bytes.value),
             "probes": self.probes,
             "fused_structures": sum(
                 1 for p in self._descend_plans.values() if p is not None),
@@ -768,38 +807,38 @@ class ProbeExecutor:
                     out_specs=row_spec, check_vma=False)
             # else: indivisible bucket — unsharded fallback, never fail
         self.compile_counts[skey] = self.compile_counts.get(skey, 0) + 1
-        self._c_compiles.inc()
         return jax.jit(_f32_matmuls(batched))
 
     # -- assembly ----------------------------------------------------------
     @staticmethod
-    def _materialize(req: ProbeRequest) -> tuple:
+    def _materialize(req: ProbeRequest, moved: "_Moves") -> tuple:
         """One request -> its group list ``(params, rows, n_rows)``.
 
         A shared-params request is ONE group of B rows; a per-row-params
         request (stage-family thetas) is B groups of one row each."""
-        x0s = jnp.asarray(req.x0s)
+        to_dev = moved.to_device
+        x0s = to_dev(req.x0s)
         B = int(x0s.shape[0])
-        los = jnp.asarray(req.los)
-        his = jnp.asarray(req.his)
+        los = to_dev(req.los)
+        his = to_dev(req.his)
         k = los.shape[-1]
         if req.bounds is not None:
-            ulo, uhi, uscale = (jnp.asarray(b) for b in req.bounds)
+            ulo, uhi, uscale = (to_dev(b) for b in req.bounds)
         else:
             ulo = jnp.full((B, k), -jnp.inf)
             uhi = jnp.full((B, k), jnp.inf)
             uscale = jnp.ones((B, k))
         alphas = (jnp.zeros((B, k)) if req.alphas is None
-                  else jnp.asarray(req.alphas))
-        targets = jnp.asarray(req.targets, dtype=jnp.int32).reshape(B)
+                  else to_dev(req.alphas))
+        targets = to_dev(req.targets, jnp.int32).reshape(B)
         rows = (x0s, los, his, ulo, uhi, uscale, alphas, targets)
         if req.params_b is None:
             # one group: (1, ...) params, (1, B, ...) rows
             params = jax.tree.map(
-                lambda a: jnp.asarray(a)[None], req.program.params)
+                lambda a: to_dev(a)[None], req.program.params)
             return params, tuple(r[None] for r in rows), 1, B
         # per-row params: B groups of one row each
-        params = jax.tree.map(lambda a: jnp.asarray(a), req.params_b)
+        params = jax.tree.map(to_dev, req.params_b)
         return params, tuple(r[:, None] for r in rows), B, 1
 
     # -- dispatch ----------------------------------------------------------
@@ -815,8 +854,11 @@ class ProbeExecutor:
         concatenated (unpadded) spans, in request order.  ``origin``
         optionally tags the dispatch source (``"frontdesk"`` for the
         async admission plane) in ``dispatch_origins`` telemetry.
-        ``parent_span`` nests the emitted ``exec.compile`` /
-        ``exec.dispatch`` spans under the caller's trace (DESIGN.md §14).
+        ``parent_span`` parents the ``exec.dispatch`` span — the whole
+        host path of the dispatch — under the caller's trace (DESIGN.md
+        §14); its children are ``exec.materialize``, ``exec.compile``
+        (bucket miss only), ``exec.pack``, ``exec.launch``, ``exec.wait``
+        and ``exec.unpack``.
         """
         requests = list(requests)
         if not requests:
@@ -829,27 +871,36 @@ class ProbeExecutor:
                 raise ValueError(
                     "solve_requests spans mix structure keys — group by "
                     "ProbeExecutor.structure_key before dispatching")
-        parts = [self._materialize(r) for r in requests]
+        with self.obs.tracer.span("exec.dispatch", cat="exec",
+                                  parent=parent_span,
+                                  args={"origin": origin,
+                                        "requests": len(requests)}) as sp:
+            return self._dispatch(requests, r0, skey, origin, sp)
+
+    def _dispatch(self, requests, r0, skey, origin, sp) -> tuple:
+        """The body of :meth:`solve_requests`, each phase a child of the
+        dispatch span ``sp``; ``exec.wait`` blocks on the outputs only
+        while tracing, so the untraced path enqueues and reads back as
+        it always has."""
+        moved = _Moves()
+        with sp.child("exec.materialize", cat="exec"):
+            parts = [self._materialize(r, moved) for r in requests]
         G = sum(p[2] for p in parts)
         R = max(p[3] for p in parts)
         S = int(jnp.shape(parts[0][1][0])[-2])
         D = int(jnp.shape(parts[0][1][0])[-1])
         k = int(jnp.shape(parts[0][1][1])[-1])
         base_key = (skey, k, S, D)
-        tr = self.obs.tracer
         with self._lock:
             plan = self._descend_plan(r0, skey)
             Gp, Rp, axis = self._choose_buckets(base_key, G, R)
             key = (*base_key, Gp, Rp)
             fn = self._programs.pop(key, None)  # re-insert as newest (LRU)
             if fn is None:
-                tc0 = tr.now()
-                fn = self._build(r0, Gp, Rp, skey, axis, plan)
-                if tr.enabled:
-                    tr.record_span(
-                        "exec.compile", tc0, tr.now(), cat="exec",
-                        parent=parent_span,
-                        args={"bucket": [Gp, Rp], "structure": str(skey)})
+                with sp.child("exec.compile", cat="exec",
+                              args={"bucket": [Gp, Rp],
+                                    "structure": str(skey)}):
+                    fn = self._build(r0, Gp, Rp, skey, axis, plan)
                 self._built_buckets.setdefault(base_key, set()).add((Gp, Rp))
             self._programs[key] = fn
             while len(self._programs) > self.max_programs:
@@ -860,44 +911,52 @@ class ProbeExecutor:
                     built.discard(old[-2:])
         # pad each part's rows to Rp, concatenate groups, pad groups to
         # Gp — all host-side numpy (see pad_rows): no per-shape jit ops
-        params = jax.tree.map(
-            lambda *ls: np.concatenate([np.asarray(a) for a in ls],
-                                       axis=0),
-            *[p[0] for p in parts])
-        rows = [
-            np.concatenate(
-                [np.asarray(pad_rows(p[1][i], Rp - p[3], axis=1))
-                 for p in parts],
-                axis=0)
-            for i in range(N_ROW_FIELDS)
-        ]
-        if Gp != G:
-            params, rows = pad_rows((params, rows), Gp - G)
-        td0 = tr.now()
-        x, f, feas = fn(params, *rows)
-        if tr.enabled:
-            tr.record_span(
-                "exec.dispatch", td0, tr.now(), cat="exec",
-                parent=parent_span,
-                args={"bucket": [Gp, Rp], "origin": origin,
-                      "fill": sum(p[2] * p[3] for p in parts) / (Gp * Rp)})
+        to_host = moved.to_host
+        with sp.child("exec.pack", cat="exec"):
+            params = jax.tree.map(
+                lambda *ls: np.concatenate([to_host(a) for a in ls],
+                                           axis=0),
+                *[p[0] for p in parts])
+            rows = [
+                np.concatenate(
+                    [pad_rows(to_host(p[1][i]), Rp - p[3], axis=1)
+                     for p in parts],
+                    axis=0)
+                for i in range(N_ROW_FIELDS)
+            ]
+            if Gp != G:
+                params, rows = pad_rows((params, rows), Gp - G)
+        with sp.child("exec.launch", cat="exec"):
+            moved.launch((params, rows))
+            x, f, feas = fn(params, *rows)
+        if sp.enabled:
+            with sp.child("exec.wait", cat="exec"):
+                jax.block_until_ready((x, f, feas))
         # slice back: group g contributes its first n_rows rows
         outs_x, outs_f, outs_feas = [], [], []
-        g0 = 0
-        for _, _, n_groups, n_rows in parts:
-            span_x = x[g0: g0 + n_groups, :n_rows]
-            outs_x.append(np.asarray(span_x).reshape(-1, span_x.shape[-1]))
-            span_f = f[g0: g0 + n_groups, :n_rows]
-            outs_f.append(np.asarray(span_f).reshape(-1, span_f.shape[-1]))
-            outs_feas.append(
-                np.asarray(feas[g0: g0 + n_groups, :n_rows]).reshape(-1))
-            g0 += n_groups
+        with sp.child("exec.unpack", cat="exec"):
+            g0 = 0
+            for _, _, n_groups, n_rows in parts:
+                span_x = x[g0: g0 + n_groups, :n_rows]
+                outs_x.append(to_host(span_x).reshape(-1, span_x.shape[-1]))
+                span_f = f[g0: g0 + n_groups, :n_rows]
+                outs_f.append(to_host(span_f).reshape(-1, span_f.shape[-1]))
+                outs_feas.append(
+                    to_host(feas[g0: g0 + n_groups, :n_rows]).reshape(-1))
+                g0 += n_groups
+            out = (np.concatenate(outs_x), np.concatenate(outs_f),
+                   np.concatenate(outs_feas))
+        useful = sum(p[2] * p[3] for p in parts)
+        sp.set("bucket", [Gp, Rp])
+        sp.set("fill", useful / (Gp * Rp))
         with self._lock:  # shared executors: keep telemetry exact
-            useful = sum(p[2] * p[3] for p in parts)
             self._c_dispatches.inc()
+            self._c_requests.inc(len(requests))
             self._c_probes.inc(useful)
             self._c_useful_rows.inc(useful)
             self._c_padded_rows.inc(Gp * Rp)
+            self._c_transfers.inc(moved.count)
+            self._c_transfer_bytes.inc(moved.bytes)
             self.last_bucket = (Gp, Rp)
             self.last_fill = useful / (Gp * Rp)
             self.last_devices = len(x.sharding.device_set)
@@ -910,8 +969,7 @@ class ProbeExecutor:
             if axis is not None:
                 self._c_sharded_dispatches.inc()
                 self.last_shard_axis = axis
-        return (np.concatenate(outs_x), np.concatenate(outs_f),
-                np.concatenate(outs_feas))
+        return out
 
     # -- batched evaluation (bounds estimation, frontier re-seeding) -------
     def eval_batch(self, program: ParamProgram, X) -> Array:
@@ -928,7 +986,6 @@ class ProbeExecutor:
                 apply = program.apply
                 fn = jax.jit(
                     _f32_matmuls(jax.vmap(apply, in_axes=(None, 0))))
-                self._c_eval_compiles.inc()
             self._evals[key] = fn
             while len(self._evals) > self.max_programs:
                 self._evals.pop(next(iter(self._evals)))

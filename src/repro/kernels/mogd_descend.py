@@ -334,6 +334,9 @@ def _descend_pallas(plan: DescendPlan, cfg, folded, x, lo, hi, ulo, uhi, us,
         out_specs=row_spec(D),
         out_shape=jax.ShapeDtypeStruct((G, Mp, D), jnp.float32),
         interpret=interpret,
+        # the kernel's own name in a device profile, apart from the
+        # snap-and-score epilogue of the jitted program around it
+        name="mogd_descend",
     )(*args)
     return out[:, :M]
 

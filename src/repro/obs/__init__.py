@@ -11,10 +11,11 @@ views over the registry.  See DESIGN.md §14.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import NOOP_SPAN, Span, Tracer
+from repro.obs.trace import ANCHOR, NOOP_SPAN, Span, Tracer, profile_anchors
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "NOOP_SPAN", "Observability", "Span", "Tracer"]
+           "NOOP_SPAN", "Observability", "Span", "Tracer",
+           "ANCHOR", "profile_anchors"]
 
 
 class Observability:

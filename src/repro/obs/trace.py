@@ -23,6 +23,14 @@ Design constraints (see DESIGN.md §14):
   the dispatch on the ``frontdesk-dispatcher`` thread, and the vault
   commit on ``frontier-vault-writer`` — the actual concurrency
   structure, not a flattened fiction.
+* **One clock with the device profile.** :meth:`Tracer.anchor` marks an
+  instant in a running JAX profile (an ``obs.anchor`` annotation) and
+  keeps the tracer's clock reading at it.  Spans are never mirrored into
+  the profiler one by one — the request path records retroactively and
+  from several threads — so the anchors alone map every span onto the
+  profile's clock (:func:`profile_anchors`, ``chrome_trace``'s
+  ``profile_anchors_ns``), where each device idle gap can be put down to
+  the host span open across it.
 """
 
 from __future__ import annotations
@@ -33,7 +41,10 @@ import json
 import threading
 import time
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["ANCHOR", "Span", "Tracer", "profile_anchors"]
+
+#: The profiler annotation :meth:`Tracer.anchor` opens.
+ANCHOR = "obs.anchor"
 
 _span_ids = itertools.count(1)
 
@@ -72,6 +83,10 @@ class Span:
         """Attach one result arg to the span."""
         self.args[key] = value
 
+    def child(self, name: str, cat: str = "", args: dict | None = None):
+        """Open a span parented to this one, on the same tracer."""
+        return self.tracer.span(name, cat, parent=self, args=args)
+
     def __enter__(self) -> "Span":
         return self
 
@@ -97,6 +112,11 @@ class _NoopSpan:
 
     def set(self, key: str, value) -> None:
         """Ignored."""
+
+    def child(self, name: str, cat: str = "",
+              args: dict | None = None) -> "_NoopSpan":
+        """The no-op span again: children of nothing record nothing."""
+        return self
 
     def end(self) -> None:
         """Ignored."""
@@ -135,6 +155,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: collections.deque = collections.deque(
             maxlen=max_spans)
+        self._anchors: list[float] = []
 
     # -- recording -----------------------------------------------------
     def span(self, name: str, cat: str = "", parent=None,
@@ -163,6 +184,29 @@ class Tracer:
         paths can bracket work without paying a clock read)."""
         return self.clock() if self.enabled else 0.0
 
+    def anchor(self) -> float | None:
+        """Mark this instant in a running JAX profile: open an
+        :data:`ANCHOR` annotation and read the tracer clock inside it.
+
+        Call it once a profile has started, and again before it stops
+        (two anchors also correct the slow drift between the profiler's
+        clock and this one).  Returns the clock reading; ``None`` when
+        disabled, which reads no clock and opens no annotation."""
+        if not self.enabled:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(ANCHOR):
+            t = self.clock()
+        with self._lock:
+            self._anchors.append(t)
+        return t
+
+    def anchors(self) -> list[float]:
+        """Tracer-clock readings of :meth:`anchor`, oldest first."""
+        with self._lock:
+            return list(self._anchors)
+
     def _record(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
@@ -174,16 +218,32 @@ class Tracer:
             return list(self._spans)
 
     def clear(self) -> None:
-        """Drop every recorded span."""
+        """Drop every recorded span and anchor."""
         with self._lock:
             self._spans.clear()
+            self._anchors.clear()
 
     def __len__(self) -> int:
         with self._lock:
             return len(self._spans)
 
     # -- export --------------------------------------------------------
-    def chrome_trace(self) -> dict:
+    def profile_clock(self, profile_anchors_ns):
+        """``t -> ns`` from the tracer clock to a profile's clock, given
+        the profile's :data:`ANCHOR` event times (:func:`profile_anchors`),
+        paired in order with :meth:`anchors`.  One pair sets the offset;
+        with more, the first and last also set the rate."""
+        mine = self.anchors()
+        n = min(len(mine), len(profile_anchors_ns))
+        if n == 0:
+            raise ValueError("no anchor pairs: call anchor() while the "
+                             "profile runs")
+        a0, p0 = mine[0], float(profile_anchors_ns[0])
+        a1, p1 = mine[n - 1], float(profile_anchors_ns[n - 1])
+        rate = (p1 - p0) / (a1 - a0) if n > 1 and a1 > a0 else 1e9
+        return lambda t: p0 + (t - a0) * rate
+
+    def chrome_trace(self, profile_anchors_ns=None) -> dict:
         """The recorded spans as a Chrome trace-event document.
 
         Load the JSON in ``chrome://tracing`` or https://ui.perfetto.dev
@@ -191,9 +251,24 @@ class Tracer:
         rebased to the earliest span, one row per real thread, plus
         thread-name metadata events.  ``span_id`` / ``parent_id`` ride
         in ``args`` so tooling can rebuild the explicit parent chain.
+
+        With ``profile_anchors_ns`` (:func:`profile_anchors` of a JAX
+        profile taken while :meth:`anchor` was called) the timestamps are
+        on that profile's clock instead: the events then line up with the
+        profile's own ``perfetto_trace.json.gz`` and can be merged into
+        it, overlaying the request path on the device's operations.
         """
         spans = self.spans()
-        origin = min((s.t0 for s in spans), default=0.0)
+        if profile_anchors_ns is None:
+            origin = min((s.t0 for s in spans), default=0.0)
+
+            def ts(t):
+                return (t - origin) * 1e6
+        else:
+            to_ns = self.profile_clock(profile_anchors_ns)
+
+            def ts(t):
+                return to_ns(t) * 1e-3
         events = []
         threads: dict[int, str] = {}
         for s in sorted(spans, key=lambda s: s.t0):
@@ -207,9 +282,9 @@ class Tracer:
                 "name": s.name,
                 "cat": s.cat or "span",
                 "ph": "X",
-                "ts": (s.t0 - origin) * 1e6,
-                "dur": max(0.0, ((s.t1 if s.t1 is not None else s.t0)
-                                 - s.t0)) * 1e6,
+                "ts": ts(s.t0),
+                "dur": max(0.0, ts(s.t1 if s.t1 is not None else s.t0)
+                           - ts(s.t0)),
                 "pid": 1,
                 "tid": tid,
                 "args": args,
@@ -223,9 +298,33 @@ class Tracer:
         } for tid, name in sorted(threads.items())]
         return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
-    def export_chrome(self, path) -> str:
+    def export_chrome(self, path, profile_anchors_ns=None) -> str:
         """Write :meth:`chrome_trace` to ``path``; returns the path."""
-        doc = self.chrome_trace()
+        doc = self.chrome_trace(profile_anchors_ns)
         with open(path, "w") as f:
             json.dump(doc, f, indent=1, default=str)
         return str(path)
+
+
+def profile_anchors(path) -> list[float]:
+    """Start times, in ns on the profile's clock, of the :data:`ANCHOR`
+    events of a JAX profile: ``path`` is an ``.xplane.pb`` file or the
+    ``log_dir`` given to ``jax.profiler.start_trace`` (its newest
+    profile)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = os.fspath(path)
+    if os.path.isdir(path):
+        files = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                          recursive=True)
+        if not files:
+            raise FileNotFoundError(f"no .xplane.pb under {path}")
+        path = max(files, key=os.path.getmtime)
+    return sorted(float(e.start_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name == ANCHOR)

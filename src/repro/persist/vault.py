@@ -76,8 +76,8 @@ class FrontierVault:
         self._queue: queue.Queue = queue.Queue()
         self._worker: threading.Thread | None = None
         # typed write-path counters (DESIGN.md §14); the int ``writes``
-        # / ``write_errors`` / ``puts_refused`` attributes stay as
-        # read-only views over the registry.
+        # / ``write_errors`` attributes stay as read-only views over the
+        # registry.
         from repro.obs import Observability
 
         self.obs = obs if obs is not None else Observability()
@@ -88,9 +88,6 @@ class FrontierVault:
         self._c_write_errors = m.counter(
             "vault.write_errors", self._labels,
             help="writes swallowed by the writer (readers just miss)")
-        self._c_puts_refused = m.counter(
-            "vault.puts_refused", self._labels,
-            help="puts refused by the tombstone ledger")
         # crash hygiene + ledger load happen at open
         self.swept_tmp = (store.sweep_tmp(self.frontiers_dir)
                           + store.sweep_tmp(self.models_dir))
@@ -104,10 +101,6 @@ class FrontierVault:
     @property
     def write_errors(self) -> int:
         return int(self._c_write_errors.value)
-
-    @property
-    def puts_refused(self) -> int:
-        return int(self._c_puts_refused.value)
 
     # -- tombstone ledger ---------------------------------------------
     def _ledger_path(self) -> pathlib.Path:
@@ -160,7 +153,6 @@ class FrontierVault:
         key = self.frontier_key(task_sig)
         with self._lock:
             if self._refused_locked(key, workload, version):
-                self._c_puts_refused.inc()
                 return False
         meta = dict(meta)
         meta.update(task_sig=task_sig, workload=workload,
@@ -308,7 +300,6 @@ class FrontierVault:
             with self._lock:
                 if kind == "frontier" and self._refused_locked(
                         key, workload, version):
-                    self._c_puts_refused.inc()
                     return
                 store.write_entry(base, key, arrays, meta, overwrite=True)
                 self._c_writes.inc()
@@ -360,6 +351,5 @@ class FrontierVault:
                 "tombstoned_workloads": len(self._tombstones["workloads"]),
                 "writes": self.writes,
                 "write_errors": self.write_errors,
-                "puts_refused": self.puts_refused,
                 "pending_writes": self._queue.unfinished_tasks,
             }

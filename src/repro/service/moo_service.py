@@ -165,10 +165,13 @@ class MOOService:
         self.use_kernel = use_kernel
         self.kernel_interpret = kernel_interpret
         # one observability bundle for the whole request path (DESIGN.md
-        # §14): an executor the service constructs shares it, and a
-        # FrontDesk in front of this service adopts it, so metrics land
-        # in one registry and spans in one tracer
-        self.obs = obs if obs is not None else Observability()
+        # §14): an executor the service constructs shares it, a given
+        # executor lends its own, and a FrontDesk in front of this
+        # service adopts it, so metrics land in one registry and spans in
+        # one tracer
+        if obs is None:
+            obs = executor.obs if executor is not None else Observability()
+        self.obs = obs
         # The service's dispatch plane (DESIGN.md §10): ALL MOGD work of
         # every session goes through this one executor, so compiled
         # programs — and their compile-count telemetry — are shared
@@ -235,12 +238,11 @@ class MOOService:
             "service.vault_snapshots", self._labels)
         self._c_vault_tombstones = m.counter(
             "service.vault_tombstones", self._labels)
-        # per-phase round timing (perf_counter seconds; always measured,
-        # tracing on or off — the frontdesk's latency attribution
-        # divides each ticket's round wall by these proportions)
-        self._h_round = {
-            p: m.histogram(f"service.round_{p}", self._labels)
-            for p in ("prepare_s", "solve_s", "absorb_s", "persist_s")}
+        # seconds each caller waited to take the service lock, recorded
+        # while the tracer is enabled (a disabled tracer reads no clock)
+        self._h_lock_wait = {
+            op: m.histogram("service.lock_wait_s", {**self._labels, "op": op})
+            for op in ("recommend", "prepare", "absorb", "persist")}
         # probe-budget allocation plane (repro.alloc, DESIGN.md §15):
         # None keeps the legacy uniform schedule with zero policy calls
         # on the hot path; the counters make the bandit's spending
@@ -253,9 +255,13 @@ class MOOService:
             "service.budget_rects_granted", self._labels)
         self._c_budget_rects_legacy = m.counter(
             "service.budget_rects_legacy", self._labels)
-        self._h_hv_gain = m.histogram(
-            "service.hv_gain", self._labels,
-            help="normalized hypervolume delta per absorbed batch")
+
+    def _lock_waited(self, op: str, t0: float) -> None:
+        """Record the wait for the service lock that began at ``t0``
+        (``tracer.now()``: 0.0 when the tracer was off)."""
+        t1 = self.obs.tracer.now()
+        if t0 and t1:
+            self._h_lock_wait[op].record(t1 - t0)
 
     # -- legacy int counter surface (views over the registry) ----------
     @property
@@ -980,8 +986,6 @@ class MOOService:
                                          round_sp, context)
         finally:
             timing["round_wall_s"] = time.perf_counter() - t_round0
-            for p in ("prepare_s", "solve_s", "absorb_s", "persist_s"):
-                self._h_round[p].record(timing[p])
             round_sp.end()
         out["timing"] = timing
         return out
@@ -994,7 +998,9 @@ class MOOService:
         out = {"batches": 0, "probes": 0, "sessions": 0,
                "per_session": {}, "exhausted": []}
         t_prep0 = time.perf_counter()
+        t_wait = tr.now()
         with self._lock:
+            self._lock_waited("prepare", t_wait)
             self._refresh_stale_locked()
             groups: dict[tuple, list[_Session]] = {}
             singles: list[_Session] = []
@@ -1070,14 +1076,22 @@ class MOOService:
                 wall = time.perf_counter() - t0
                 timing["solve_s"] += wall
                 t_abs0 = time.perf_counter()
-                with self._lock:
+                absorb_sp = tr.span("service.absorb", cat="service",
+                                    parent=round_sp, args={"rows": total})
+                # the parent rides only while tracing, so absorb
+                # overrides with the older signature keep working
+                span_kw = {"span": absorb_sp} if absorb_sp.enabled else {}
+                t_wait = tr.now()
+                with absorb_sp, self._lock:
+                    self._lock_waited("absorb", t_wait)
                     off = 0
                     for s, cells, boxes, pop in prepared:
                         n = boxes.shape[0]
                         sub = dataclasses.replace(
                             res, x=res.x[off: off + n], f=res.f[off: off + n],
                             feasible=res.feasible[off: off + n])
-                        s.engine.absorb(s.state, cells, sub, pop=pop)
+                        s.engine.absorb(s.state, cells, sub, pop=pop,
+                                        **span_kw)
                         # charge each session its share of the dispatch
                         s.state.elapsed += wall * (n / total)
                         s.state.record()
@@ -1086,7 +1100,6 @@ class MOOService:
                         # fold it into the session's per-probe EMA and
                         # feed the policy its realized reward
                         delta = s.state.gain_log[-1][1]
-                        self._h_hv_gain.record(delta)
                         s.gain_ema = (0.7 * s.gain_ema
                                       + 0.3 * (delta / max(n, 1)))
                         s.rounds_idle = 0
@@ -1106,12 +1119,7 @@ class MOOService:
                     out["batches"] += 1
                     out["probes"] += total
                     out["sessions"] += len(prepared)
-                t_abs1 = time.perf_counter()
-                timing["absorb_s"] += t_abs1 - t_abs0
-                if tr.enabled:
-                    tr.record_span("service.absorb", t_abs0, t_abs1,
-                                   cat="service", parent=round_sp,
-                                   args={"rows": total})
+                timing["absorb_s"] += time.perf_counter() - t_abs0
         except Exception:
             # a failed shared dispatch must not leak any tenant's popped
             # uncertain space — return every unsolved cell to its queue
@@ -1152,7 +1160,9 @@ class MOOService:
         if self.vault is not None:
             t_per0 = time.perf_counter()
             persisted = 0
+            t_wait = tr.now()
             with self._lock:
+                self._lock_waited("persist", t_wait)
                 for sess in sessions:
                     if self._sessions.get(sess.session_id) is not sess:
                         continue
@@ -1231,7 +1241,9 @@ class MOOService:
             preference = preference_from_legacy(
                 strategy if strategy is not None else preference,
                 weights=weights, default_latency_s=default_latency_s)
+        t_wait = self.obs.tracer.now()
         with self._lock:
+            self._lock_waited("recommend", t_wait)
             sess = self._get(session_id)
             if preference is None:
                 preference = (sess.spec.preference if sess.spec is not None

@@ -2,9 +2,11 @@
 
 Covers the typed metrics registry (identity, exports, merge, thread
 safety under concurrent writers), the tracer (no-op fast path, explicit
-parent chains, Chrome export shape), the per-ticket latency
-attribution invariant (phase breakdown sums to end-to-end latency),
-and the counter-drift regression for the dispatch exception path.
+parent chains, Chrome export shape, anchors onto a JAX profile's clock),
+the dispatch plane's transfer counters, the service-lock wait
+histograms, the per-ticket latency attribution invariant (phase
+breakdown sums to end-to-end latency), and the counter-drift regression
+for the dispatch exception path.
 """
 
 from __future__ import annotations
@@ -12,12 +14,15 @@ from __future__ import annotations
 import json
 import math
 import threading
+import time
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import MOGDConfig
 from repro.core.synthetic import mlp_surrogate_task
+from repro.exec import ProbeExecutor
 from repro.frontdesk import FrontDesk
 from repro.obs import (
     NOOP_SPAN,
@@ -27,7 +32,9 @@ from repro.obs import (
     MetricsRegistry,
     Observability,
     Tracer,
+    profile_anchors,
 )
+from repro.obs import trace as trace_mod
 from repro.service import MOOService
 
 FAST = MOGDConfig(steps=12, multistart=2)
@@ -243,6 +250,71 @@ class TestTracer:
         doc = json.loads(open(path).read())
         assert any(e["name"] == "x" for e in doc["traceEvents"])
 
+    def test_child_spans_follow_their_parent(self):
+        tr = Tracer(enabled=True)
+        with tr.span("outer") as outer:
+            with outer.child("inner", cat="c", args={"n": 2}) as inner:
+                pass
+        assert inner.parent_id == outer.span_id and inner.args == {"n": 2}
+        assert NOOP_SPAN.child("x") is NOOP_SPAN
+
+    def test_disabled_anchor_reads_no_clock(self):
+        def clock():
+            raise AssertionError("a disabled tracer read its clock")
+
+        tr = Tracer(enabled=False, clock=clock)
+        assert tr.anchor() is None and tr.anchors() == []
+        assert tr.now() == 0.0
+
+    def test_profile_clock_pairs_anchors_in_order(self):
+        ticks = iter([10.0, 30.0])
+        tr = Tracer(enabled=True, clock=lambda: next(ticks))
+        tr.anchor()
+        tr.anchor()
+        # the profile ran 1e-6 fast against the tracer between anchors
+        to_ns = tr.profile_clock([5e9, 25e9 + 20e3])
+        assert to_ns(10.0) == 5e9
+        assert to_ns(20.0) == pytest.approx(15e9 + 10e3)
+        with pytest.raises(ValueError):
+            Tracer(enabled=True).profile_clock([1.0])
+        tr.clear()
+        assert tr.anchors() == []
+
+    def test_anchor_maps_spans_onto_a_cpu_profile(self, tmp_path):
+        """The anchor's profiler event and the tracer's reading inside it
+        put a span opened around a TraceAnnotation onto that annotation,
+        within 1 ms, at both ends."""
+        import jax
+
+        tr = Tracer(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            tr.anchor()
+            time.sleep(0.02)
+            with tr.span("work") as sp:
+                with jax.profiler.TraceAnnotation("work.annotation"):
+                    time.sleep(0.005)
+            time.sleep(0.02)
+            tr.anchor()
+        finally:
+            jax.profiler.stop_trace()
+        anchors = profile_anchors(str(tmp_path))
+        assert len(anchors) == 2
+        ann = [(e.start_ns, e.start_ns + e.duration_ns)
+               for plane in jax.profiler.ProfileData.from_file(
+                   str(next(tmp_path.rglob("*.xplane.pb")))).planes
+               for line in plane.lines for e in line.events
+               if e.name == "work.annotation"]
+        assert len(ann) == 1
+        a0, a1 = ann[0]
+        to_ns = tr.profile_clock(anchors)
+        assert abs(to_ns(sp.t0) - a0) < 1e6
+        assert abs(to_ns(sp.t1) - a1) < 1e6
+        doc = tr.chrome_trace(profile_anchors_ns=anchors)
+        ev = next(e for e in doc["traceEvents"] if e["name"] == "work")
+        assert abs(ev["ts"] - a0 * 1e-3) < 1e3
+        assert abs(ev["ts"] + ev["dur"] - a1 * 1e-3) < 1e3
+
 
 # -- stats() surfaces stay views over the registry -------------------------
 
@@ -260,6 +332,16 @@ class TestStatsViews:
         # the whole stack shares ONE registry
         assert svc.executor.obs.metrics is svc.obs.metrics
 
+    def test_service_adopts_a_given_executors_bundle(self):
+        ex = ProbeExecutor(mesh=None)
+        svc = MOOService(mogd=FAST, batch_rects=1, grid_l=2, executor=ex,
+                         mesh=None)
+        assert svc.obs is ex.obs
+        assert FrontDesk(svc, capacity=4).obs is ex.obs
+        own = Observability()
+        assert MOOService(mogd=FAST, executor=ex, mesh=None,
+                          obs=own).obs is own
+
     def test_registry_backs_legacy_int_surface(self):
         svc = MOOService(mogd=FAST, batch_rects=1, grid_l=2)
         sid = svc.create_session(mlp_surrogate_task(seed=0))
@@ -270,6 +352,51 @@ class TestStatsViews:
         snap = svc.obs.metrics.snapshot()
         key = next(k for k in snap if k.startswith("exec.dispatches{"))
         assert snap[key]["value"] == ex.dispatches
+
+
+# -- dispatch plane: transfer counters -------------------------------------
+
+class TestTransferCounters:
+    def test_two_request_dispatch_counts_exactly(self):
+        """Two coalesced requests, host-side starts and boxes, parameters
+        already on the device: every array that crosses is counted, at
+        the dtype it crosses in."""
+        from repro.core.mogd import MOGDSolver
+        from repro.core.task import as_problem
+        from repro.exec.executor import N_ROW_FIELDS
+
+        ex = ProbeExecutor(mesh=None)
+        reqs, sizes = [], (3, 5)
+        for i, B in enumerate(sizes):
+            solver = MOGDSolver(as_problem(mlp_surrogate_task(seed=i)),
+                                FAST, executor=ex)
+            D, k = solver.problem.dim, solver.problem.k
+            x0s = np.random.default_rng(i).random((B, FAST.multistart, D))
+            reqs.append(solver._request(x0s, np.zeros((B, k)),
+                                        np.ones((B, k)), 0))
+        leaves = jax.tree.leaves(reqs[0].program.params)
+        assert all(not isinstance(a, np.ndarray) for a in leaves)
+        L = len(leaves)
+        P = sum(int(a.size) * 4 for a in leaves)
+        S = FAST.multistart
+        x, f, feas = ex.solve_requests(reqs)
+        assert len(x) == sum(sizes)
+        st = ex.stats()
+        assert st["requests"] == 2 and st["dispatches"] == 1
+        # per request: x0s, los, his, targets to the device; parameters
+        # and the 8 row fields back for packing; x, f, feasible back.
+        # Once: the packed parameters and rows to the device.
+        assert N_ROW_FIELDS == 8
+        assert st["transfers"] == 2 * (4 + L + 8 + 3) + (L + 8)
+        Gp, Rp = ex.last_bucket
+        assert (Gp, Rp) == (2, 8)
+        want = 0
+        for B in sizes:
+            want += (B * S * D + 2 * B * k + B) * 4          # materialize
+            want += P + (B * S * D + 6 * B * k + B) * 4      # pack
+            want += B * D * 4 + B * k * 4 + B                # unpack
+        want += Gp * P + Gp * Rp * (S * D + 6 * k + 1) * 4   # launch
+        assert st["transfer_bytes"] == want
 
 
 # -- end-to-end: request-path trace + latency attribution ------------------
@@ -308,10 +435,13 @@ class TestServingTrace:
         # -- taxonomy: the request path appears, correctly nested
         spans = {s.span_id: s for s in obs.tracer.spans()}
         names = {s.name for s in spans.values()}
+        exec_phases = {"exec.materialize", "exec.pack", "exec.launch",
+                       "exec.wait", "exec.unpack"}
         assert {"frontdesk.admit", "frontdesk.schedule",
                 "frontdesk.dispatch", "service.step_round",
                 "service.prepare", "service.solve", "service.absorb",
-                "exec.dispatch"} <= names
+                "exec.dispatch", "pf.absorb", "store.dominance"
+                } | exec_phases <= names
 
         def parents_of(name):
             out = set()
@@ -323,6 +453,24 @@ class TestServingTrace:
         assert parents_of("service.step_round") == {"frontdesk.dispatch"}
         assert parents_of("service.solve") == {"service.step_round"}
         assert parents_of("exec.dispatch") <= {"service.solve"}
+        assert "service.solve" in parents_of("exec.dispatch")
+        for name in exec_phases | {"exec.compile"}:
+            assert parents_of(name) <= {"exec.dispatch"}, name
+        assert parents_of("service.absorb") == {"service.step_round"}
+        assert parents_of("pf.absorb") == {"service.absorb"}
+        assert parents_of("store.dominance") <= {"pf.absorb"}
+        assert "pf.absorb" in parents_of("store.dominance")
+        # each served dispatch's phases sit inside it, one of each
+        for d in (s for s in spans.values() if s.name == "exec.dispatch"
+                  and s.parent_id in spans):
+            kids = [s.name for s in spans.values() if s.parent_id == d.span_id]
+            assert sorted(set(kids) - {"exec.compile"}) == sorted(
+                exec_phases)
+            assert len(kids) == len(set(kids))
+        # the lock waits of the traced round were recorded
+        waits = {i.labels["op"]: i.count for i in
+                 obs.metrics.instruments("service.lock_wait_s")}
+        assert waits["prepare"] >= 1 and waits["absorb"] >= 1
         # every child interval nests inside its parent's
         for s in spans.values():
             if s.parent_id in spans:
@@ -348,6 +496,40 @@ class TestServingTrace:
         assert t.ok and len(svc.obs.tracer) == 0
         b = t.breakdown()
         assert b["accounted_s"] == pytest.approx(b["e2e_s"], abs=1e-6)
+
+    def test_disabled_tracer_reads_no_clock_and_allocates_no_span(
+            self, monkeypatch):
+        """The dispatch phases, the absorb spans and the lock-wait
+        brackets cost a disabled tracer no clock read and no span."""
+        def clock():
+            raise AssertionError("a disabled tracer read its clock")
+
+        def no_span(*a, **kw):
+            raise AssertionError("a disabled tracer allocated a span")
+
+        monkeypatch.setattr(trace_mod.Span, "__init__", no_span)
+        obs = Observability(tracer=Tracer(enabled=False, clock=clock))
+        svc = MOOService(mogd=FAST, batch_rects=2, grid_l=2, obs=obs)
+        sid = svc.create_session(mlp_surrogate_task(seed=0))
+        for _ in range(2):
+            svc.step_sessions([sid], origin="test")
+        assert svc.recommend(sid).frontier_size >= 1
+        assert len(obs.tracer) == 0 and obs.tracer.anchor() is None
+        assert all(i.count == 0 for i in
+                   obs.metrics.instruments("service.lock_wait_s"))
+        assert svc.executor.stats()["transfers"] > 0  # counters stay on
+
+    def test_recommend_lock_wait_recorded_while_tracing(self):
+        obs = Observability(trace=True)
+        svc = MOOService(mogd=FAST, batch_rects=2, grid_l=2, obs=obs)
+        sid = svc.create_session(mlp_surrogate_task(seed=0))
+        svc.step_sessions([sid], origin="test")
+        for _ in range(3):
+            svc.recommend(sid)
+        rec = [i for i in obs.metrics.instruments("service.lock_wait_s")
+               if i.labels["op"] == "recommend"]
+        assert len(rec) == 1 and rec[0].count == 3
+        assert 0.0 <= rec[0].quantile(0.99) < 1.0
 
 
 # -- counter drift on the dispatch exception path --------------------------
