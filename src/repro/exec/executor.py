@@ -196,6 +196,12 @@ class ParamProgram:
     params: Any
     structure: tuple
     apply_std: Callable | None = None
+    # ``(params, host copy)``: the executor's numpy copy of ``params``
+    # with a leading group axis, read back once on first use.  Keyed by
+    # the identity of ``params``; ``dataclasses.replace`` starts a
+    # derived program without one.
+    _host_params: Any = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
 
 def closure_program(fn: Callable, token) -> ParamProgram:
@@ -299,13 +305,17 @@ class ProbeRequest:
 class _Moves:
     """Counts the arrays one dispatch moves between host and device
     (``exec.transfers`` / ``exec.transfer_bytes``): host data handed to
-    the device, and device arrays read back to the host."""
+    the device, and device arrays read back to the host.  ``param_hits``
+    and ``param_misses`` count the requests whose parameters came from
+    their program's host copy, and those that had to make it."""
 
-    __slots__ = ("count", "bytes")
+    __slots__ = ("count", "bytes", "param_hits", "param_misses")
 
     def __init__(self):
         self.count = 0
         self.bytes = 0
+        self.param_hits = 0
+        self.param_misses = 0
 
     def _add(self, a) -> None:
         # host data crosses at its canonical dtype (float64 as float32)
@@ -313,18 +323,14 @@ class _Moves:
         self.bytes += int(np.size(a)) * jax.dtypes.canonicalize_dtype(
             a.dtype).itemsize
 
-    def to_device(self, a, dtype=None):
-        """``jnp.asarray(a, dtype)``, counted when ``a`` is host data."""
-        if not isinstance(a, jax.Array):
-            a = np.asarray(a, dtype)
-            self._add(a)
-        return jnp.asarray(a, dtype)
-
-    def to_host(self, a) -> np.ndarray:
-        """``np.asarray(a)``, counted when ``a`` is a device array."""
+    def to_host(self, a, dtype=None) -> np.ndarray:
+        """``a`` as host numpy at the dtype ``jnp.asarray(a, dtype)``
+        would give it (float64 becomes float32 with x64 off); a device
+        array is read back, and counted."""
         if isinstance(a, jax.Array):
             self._add(a)
-        return np.asarray(a)
+        a = np.asarray(a, dtype)
+        return a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
 
     def launch(self, args) -> None:
         """Count the host leaves a jitted call will copy to the device."""
@@ -428,6 +434,12 @@ class ProbeExecutor:
         self._c_transfer_bytes = m.counter(
             "exec.transfer_bytes", self._labels,
             help="bytes of the arrays exec.transfers counts")
+        self._c_param_hits = m.counter(
+            "exec.host_param_hits", self._labels,
+            help="requests whose parameters came from the host copy")
+        self._c_param_misses = m.counter(
+            "exec.host_param_misses", self._labels,
+            help="requests whose parameters were read back to the host")
         self._c_probes = m.counter(
             "exec.probes", self._labels, help="useful probe rows solved")
         self._c_fused_dispatches = m.counter(
@@ -508,6 +520,8 @@ class ProbeExecutor:
             "requests": int(self._c_requests.value),
             "transfers": int(self._c_transfers.value),
             "transfer_bytes": int(self._c_transfer_bytes.value),
+            "host_param_hits": int(self._c_param_hits.value),
+            "host_param_misses": int(self._c_param_misses.value),
             "probes": self.probes,
             "fused_structures": sum(
                 1 for p in self._descend_plans.values() if p is not None),
@@ -811,34 +825,49 @@ class ProbeExecutor:
 
     # -- assembly ----------------------------------------------------------
     @staticmethod
-    def _materialize(req: ProbeRequest, moved: "_Moves") -> tuple:
-        """One request -> its group list ``(params, rows, n_rows)``.
+    def _host_copy(program: ParamProgram, moved: "_Moves"):
+        """``program.params`` as host numpy with a leading group axis of
+        one: read back on the program's first dispatch, then reused for
+        as long as ``program.params`` is the same object."""
+        cached = program._host_params
+        if cached is not None and cached[0] is program.params:
+            moved.param_hits += 1
+            return cached[1]
+        moved.param_misses += 1
+        host = jax.tree.map(lambda a: moved.to_host(a)[None], program.params)
+        program._host_params = (program.params, host)
+        return host
+
+    @classmethod
+    def _materialize(cls, req: ProbeRequest, moved: "_Moves") -> tuple:
+        """One request -> its group list ``(params, rows, n_groups,
+        n_rows)``, all host numpy: the dispatch crosses to the device
+        once, at the program call.
 
         A shared-params request is ONE group of B rows; a per-row-params
         request (stage-family thetas) is B groups of one row each."""
-        to_dev = moved.to_device
-        x0s = to_dev(req.x0s)
+        to_host = moved.to_host
+        x0s = to_host(req.x0s)
         B = int(x0s.shape[0])
-        los = to_dev(req.los)
-        his = to_dev(req.his)
+        los = to_host(req.los)
+        his = to_host(req.his)
         k = los.shape[-1]
         if req.bounds is not None:
-            ulo, uhi, uscale = (to_dev(b) for b in req.bounds)
+            ulo, uhi, uscale = (to_host(b) for b in req.bounds)
         else:
-            ulo = jnp.full((B, k), -jnp.inf)
-            uhi = jnp.full((B, k), jnp.inf)
-            uscale = jnp.ones((B, k))
-        alphas = (jnp.zeros((B, k)) if req.alphas is None
-                  else to_dev(req.alphas))
-        targets = to_dev(req.targets, jnp.int32).reshape(B)
+            ulo = np.full((B, k), -np.inf, np.float32)
+            uhi = np.full((B, k), np.inf, np.float32)
+            uscale = np.ones((B, k), np.float32)
+        alphas = (np.zeros((B, k), np.float32) if req.alphas is None
+                  else to_host(req.alphas))
+        targets = to_host(req.targets, np.int32).reshape(B)
         rows = (x0s, los, his, ulo, uhi, uscale, alphas, targets)
         if req.params_b is None:
             # one group: (1, ...) params, (1, B, ...) rows
-            params = jax.tree.map(
-                lambda a: to_dev(a)[None], req.program.params)
+            params = cls._host_copy(req.program, moved)
             return params, tuple(r[None] for r in rows), 1, B
         # per-row params: B groups of one row each
-        params = jax.tree.map(to_dev, req.params_b)
+        params = jax.tree.map(to_host, req.params_b)
         return params, tuple(r[:, None] for r in rows), B, 1
 
     # -- dispatch ----------------------------------------------------------
@@ -887,9 +916,8 @@ class ProbeExecutor:
             parts = [self._materialize(r, moved) for r in requests]
         G = sum(p[2] for p in parts)
         R = max(p[3] for p in parts)
-        S = int(jnp.shape(parts[0][1][0])[-2])
-        D = int(jnp.shape(parts[0][1][0])[-1])
-        k = int(jnp.shape(parts[0][1][1])[-1])
+        S, D = parts[0][1][0].shape[-2:]
+        k = parts[0][1][1].shape[-1]
         base_key = (skey, k, S, D)
         with self._lock:
             plan = self._descend_plan(r0, skey)
@@ -911,16 +939,13 @@ class ProbeExecutor:
                     built.discard(old[-2:])
         # pad each part's rows to Rp, concatenate groups, pad groups to
         # Gp — all host-side numpy (see pad_rows): no per-shape jit ops
-        to_host = moved.to_host
         with sp.child("exec.pack", cat="exec"):
             params = jax.tree.map(
-                lambda *ls: np.concatenate([to_host(a) for a in ls],
-                                           axis=0),
+                lambda *ls: np.concatenate(ls, axis=0),
                 *[p[0] for p in parts])
             rows = [
                 np.concatenate(
-                    [pad_rows(to_host(p[1][i]), Rp - p[3], axis=1)
-                     for p in parts],
+                    [pad_rows(p[1][i], Rp - p[3], axis=1) for p in parts],
                     axis=0)
                 for i in range(N_ROW_FIELDS)
             ]
@@ -932,17 +957,17 @@ class ProbeExecutor:
         if sp.enabled:
             with sp.child("exec.wait", cat="exec"):
                 jax.block_until_ready((x, f, feas))
-        # slice back: group g contributes its first n_rows rows
-        outs_x, outs_f, outs_feas = [], [], []
+        # read the results back once, then slice them per request on the
+        # host: group g contributes its first n_rows rows
         with sp.child("exec.unpack", cat="exec"):
+            xh, fh, feash = (moved.to_host(a) for a in (x, f, feas))
+            outs_x, outs_f, outs_feas = [], [], []
             g0 = 0
             for _, _, n_groups, n_rows in parts:
-                span_x = x[g0: g0 + n_groups, :n_rows]
-                outs_x.append(to_host(span_x).reshape(-1, span_x.shape[-1]))
-                span_f = f[g0: g0 + n_groups, :n_rows]
-                outs_f.append(to_host(span_f).reshape(-1, span_f.shape[-1]))
-                outs_feas.append(
-                    to_host(feas[g0: g0 + n_groups, :n_rows]).reshape(-1))
+                span = slice(g0, g0 + n_groups), slice(0, n_rows)
+                outs_x.append(xh[span].reshape(-1, xh.shape[-1]))
+                outs_f.append(fh[span].reshape(-1, fh.shape[-1]))
+                outs_feas.append(feash[span].reshape(-1))
                 g0 += n_groups
             out = (np.concatenate(outs_x), np.concatenate(outs_f),
                    np.concatenate(outs_feas))
@@ -957,6 +982,8 @@ class ProbeExecutor:
             self._c_padded_rows.inc(Gp * Rp)
             self._c_transfers.inc(moved.count)
             self._c_transfer_bytes.inc(moved.bytes)
+            self._c_param_hits.inc(moved.param_hits)
+            self._c_param_misses.inc(moved.param_misses)
             self.last_bucket = (Gp, Rp)
             self.last_fill = useful / (Gp * Rp)
             self.last_devices = len(x.sharding.device_set)

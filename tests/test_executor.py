@@ -14,7 +14,10 @@ Covers the dispatch-plane contract end to end:
   reuses the warm executor with ZERO new compilations (the compile-count
   telemetry asserted here gates CI via the service benchmark);
 * the opt-in mesh path is a no-op on one device and bit-compatible on an
-  8-device host mesh (subprocess, like tests/test_distributed.py).
+  8-device host mesh (subprocess, like tests/test_distributed.py);
+* a dispatch assembled in host numpy hands the program the same inputs,
+  bit for bit, as one assembled on the device, and a promoted snapshot
+  makes its own host copy of its parameters.
 """
 
 import json
@@ -312,6 +315,210 @@ class TestStructureSharing:
                          executor=ProbeExecutor()).solve(
             boxes_for(problem, 4))
         assert not res.feasible.any()
+
+
+# ---------------------------------------------------------------------------
+# Host assembly: a dispatch is built in numpy and crosses once
+# ---------------------------------------------------------------------------
+
+
+def _device_assembled(ex: ProbeExecutor, requests):
+    """Call the program ``ex`` last dispatched on inputs assembled on the
+    device, as the executor once did: every row field and parameter leaf
+    put on the device, the group axis added and open bounds filled there,
+    the batch concatenated and padded with device ops; results sliced on
+    the device per request and read back."""
+    (fn,) = [f for key, f in ex._programs.items()
+             if key[-2:] == ex.last_bucket]
+    Gp, Rp = ex.last_bucket
+    parts = []
+    for req in requests:
+        x0s = jnp.asarray(req.x0s)
+        B = x0s.shape[0]
+        los, his = jnp.asarray(req.los), jnp.asarray(req.his)
+        k = los.shape[-1]
+        if req.bounds is not None:
+            ulo, uhi, uscale = (jnp.asarray(b) for b in req.bounds)
+        else:
+            ulo, uhi = jnp.full((B, k), -jnp.inf), jnp.full((B, k), jnp.inf)
+            uscale = jnp.ones((B, k))
+        alphas = (jnp.zeros((B, k)) if req.alphas is None
+                  else jnp.asarray(req.alphas))
+        targets = jnp.asarray(req.targets, jnp.int32).reshape(B)
+        rows = (x0s, los, his, ulo, uhi, uscale, alphas, targets)
+        if req.params_b is None:
+            params = jax.tree.map(lambda a: jnp.asarray(a)[None],
+                                  req.program.params)
+            parts.append((params, tuple(r[None] for r in rows), 1, B))
+        else:
+            params = jax.tree.map(jnp.asarray, req.params_b)
+            parts.append((params, tuple(r[:, None] for r in rows), B, 1))
+
+    def pad(a, n, axis):
+        if n == 0:
+            return a
+        edge = jnp.take(a, jnp.zeros((n,), jnp.int32), axis=axis)
+        return jnp.concatenate([a, edge], axis=axis)
+
+    G = sum(p[2] for p in parts)
+    params = jax.tree.map(lambda *ls: pad(jnp.concatenate(ls), Gp - G, 0),
+                          *[p[0] for p in parts])
+    rows = [pad(jnp.concatenate([pad(p[1][i], Rp - p[3], 1)
+                                 for p in parts]), Gp - G, 0)
+            for i in range(len(parts[0][1]))]
+    x, f, feas = fn(params, *rows)
+    outs, g0 = [], 0
+    for _, _, n_groups, n_rows in parts:
+        outs.append([np.asarray(a[g0:g0 + n_groups, :n_rows]).reshape(
+            n_groups * n_rows, *a.shape[2:]) for a in (x, f, feas)])
+        g0 += n_groups
+    return tuple(np.concatenate(o) for o in zip(*outs))
+
+
+def _gp_workload(i: int, d: int = 3, alpha: float = 0.0) -> TaskSpec:
+    """A two-objective GP-backed workload (scan path); ``alpha`` weights
+    the first objective's predictive std."""
+    from repro.exec import stack_programs
+
+    rng = np.random.default_rng(100 + i)
+    X = rng.random((12, d))
+    regs = [fit_gp(X, X @ rng.random(d) + 0.1 * rng.normal(size=12))
+            for _ in range(2)]
+    return TaskSpec(
+        knobs=tuple(continuous(f"x{t}", 0.0, 1.0) for t in range(d)),
+        objectives=(Objective("f0", alpha=alpha), Objective("f1")),
+        program=stack_programs([r.as_program() for r in regs]),
+        name=f"gp-wl-{i}")
+
+
+def _starts(i: int, shape) -> jax.Array:
+    return jax.random.uniform(jax.random.PRNGKey(50 + i), shape)
+
+
+def _mlp_pair():
+    """Two coalesced MLP tenants, the second with a bound on its first
+    objective; the first open.  Device-drawn starts, as solve_grouped
+    draws them."""
+    ex = ProbeExecutor(mesh=None)
+    reqs = []
+    for i, (spec, B, target) in enumerate(
+            [(mlp_workload(0), 3, 0),
+             (mlp_workload(1, bound=(None, 0.5)), 5, 1)]):
+        s = MOGDSolver(spec.compile(), FAST, executor=ex)
+        boxes = boxes_for(s.problem, B, seed=i)
+        reqs.append(s._request(_starts(i, (B, FAST.multistart, 3)),
+                               boxes[:, 0], boxes[:, 1], target))
+    return ex, reqs, ex.solve_requests(reqs)
+
+
+def _gp_pair():
+    """Two coalesced GP tenants on the scan path, weighting their
+    predictive std (the alphas row field)."""
+    ex = ProbeExecutor(mesh=None)
+    reqs = []
+    for i in range(2):
+        s = MOGDSolver(_gp_workload(i, alpha=0.5).compile(), FAST,
+                       executor=ex)
+        boxes = boxes_for(s.problem, 4, seed=i)
+        reqs.append(s._request(_starts(i, (4, FAST.multistart, 3)),
+                               boxes[:, 0], boxes[:, 1], 0))
+    out = ex.solve_requests(reqs)
+    assert ex.fused_dispatches == 0
+    assert all(r.alphas is not None for r in reqs)
+    return ex, reqs, out
+
+
+def _family():
+    """Stage-family thetas: per-row parameters, B groups of one row."""
+    from repro.core.dag import FamilySolver, make_analytics_family
+    from repro.exec import ProbeRequest
+
+    ex = ProbeExecutor(mesh=None)
+    fam = FamilySolver(make_analytics_family(), FAST, executor=ex)
+    rng = np.random.default_rng(3)
+    B = 5
+    thetas = np.stack([rng.uniform(1, 4, B), rng.uniform(0, 1, B),
+                       rng.uniform(0, 1, B), rng.uniform(0.5, 2, B)], 1)
+    lo = np.stack([rng.uniform(0.5, 2, B), rng.uniform(0.5, 4, B)], 1)
+    req = ProbeRequest(
+        program=fam._program, encoder=fam.family.encoder, cfg=FAST,
+        x0s=_starts(0, (B, FAST.multistart, fam.family.encoder.dim)),
+        los=lo, his=lo + 3.0, targets=np.zeros((B,), np.int32),
+        params_b=thetas)
+    return ex, [req], ex.solve_requests([req])
+
+
+def _refine():
+    """``MOGDSolver.refine``: host float64 starts, one start per row."""
+    ex = ProbeExecutor(mesh=None)
+    s = MOGDSolver(mlp_workload(2).compile(), FAST, executor=ex)
+    x0s = np.random.default_rng(4).random((5, 3))
+    box = boxes_for(s.problem, 1)[0]
+    out = s.refine(x0s, box)
+    lo = np.broadcast_to(box[0], (5, 2))
+    hi = np.broadcast_to(box[1], (5, 2))
+    return ex, [s._request(x0s[:, None, :], lo, hi, 0)], out
+
+
+class TestHostAssembly:
+    @pytest.mark.parametrize("case", [_mlp_pair, _gp_pair, _family,
+                                      _refine],
+                             ids=["mlp_pair", "gp_scan", "family",
+                                  "refine"])
+    def test_matches_device_assembly_bit_for_bit(self, case):
+        """The program sees the same inputs whether the dispatch was
+        assembled in numpy or on the device."""
+        ex, reqs, got = case()
+        want = _device_assembled(ex, reqs)
+        assert len(got) == len(want) == 3
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(g, w)
+
+    def test_promoted_snapshot_makes_its_own_host_copy(self):
+        """A promotion builds a new program: its first dispatch reads the
+        new weights back instead of reusing the predecessor's copy."""
+        from repro.modelserver import ModelRegistry, TrainerConfig
+
+        rng = np.random.default_rng(0)
+        knobs = (continuous("a", 0.0, 1.0), continuous("b", 0.0, 1.0))
+
+        def truth(X, shift=False):
+            y1 = (3.0 if shift else 1.0) * (X[:, 0] - 0.3) ** 2 + X[:, 1]
+            return np.stack([y1 + 0.5, 1.5 - X[:, 0] + 0.2 * X[:, 1]], 1)
+
+        reg = ModelRegistry(
+            trainer=TrainerConfig(hidden=(16, 16), max_epochs=25, seed=0))
+        w = reg.register_workload(("exec", "promo"), knobs,
+                                  (Objective("lat"), Objective("cost")))
+        X = rng.random((140, 2))
+        reg.observe_batch(w, X, truth(X))
+        assert reg.retrain(w).improved
+        ex = ProbeExecutor(mesh=None)
+
+        def solve_v():
+            s = MOGDSolver(reg.task_spec(w).compile(), FAST, executor=ex)
+            s.solve(boxes_for(s.problem, 3))
+            return s.program()
+
+        old = solve_v()
+        solve_v()
+        assert ex.stats()["host_param_misses"] == 1
+        assert ex.stats()["host_param_hits"] == 1
+        X = rng.random((160, 2))
+        reg.observe_batch(w, X, truth(X, shift=True))
+        assert reg.retrain(w).version == 2
+        new = solve_v()
+        assert new is not old
+        st = ex.stats()
+        assert st["host_param_misses"] == 2 and st["host_param_hits"] == 1
+        host = jax.tree.leaves(new._host_params[1])
+        assert new._host_params[0] is new.params
+        for h, a in zip(host, jax.tree.leaves(new.params)):
+            assert np.array_equal(h[0], np.asarray(a))
+        assert not all(
+            np.array_equal(a, b) for a, b in zip(
+                host, jax.tree.leaves(old._host_params[1])))
 
 
 # ---------------------------------------------------------------------------

@@ -357,46 +357,87 @@ class TestStatsViews:
 # -- dispatch plane: transfer counters -------------------------------------
 
 class TestTransferCounters:
+    @staticmethod
+    def _requests(ex, sizes, starts):
+        from repro.core.mogd import MOGDSolver
+
+        reqs = []
+        for i, B in enumerate(sizes):
+            # compiled afresh: programs from as_problem's cache may hold
+            # host copies made by earlier tests
+            solver = MOGDSolver(mlp_surrogate_task(seed=i).compile(),
+                                FAST, executor=ex)
+            D, k = solver.problem.dim, solver.problem.k
+            x0s = starts(i, (B, FAST.multistart, D))
+            reqs.append(solver._request(x0s, np.zeros((B, k)),
+                                        np.ones((B, k)), 0))
+        return reqs
+
     def test_two_request_dispatch_counts_exactly(self):
         """Two coalesced requests, host-side starts and boxes, parameters
-        already on the device: every array that crosses is counted, at
-        the dtype it crosses in."""
-        from repro.core.mogd import MOGDSolver
-        from repro.core.task import as_problem
+        on the device: host data is not counted until the launch, each
+        program's parameters are read back once into its host copy, and
+        the results cross back once per dispatch.  A second identical
+        dispatch reads no parameters."""
         from repro.exec.executor import N_ROW_FIELDS
 
         ex = ProbeExecutor(mesh=None)
-        reqs, sizes = [], (3, 5)
-        for i, B in enumerate(sizes):
-            solver = MOGDSolver(as_problem(mlp_surrogate_task(seed=i)),
-                                FAST, executor=ex)
-            D, k = solver.problem.dim, solver.problem.k
-            x0s = np.random.default_rng(i).random((B, FAST.multistart, D))
-            reqs.append(solver._request(x0s, np.zeros((B, k)),
-                                        np.ones((B, k)), 0))
+        sizes = (3, 5)
+        reqs = self._requests(
+            ex, sizes, lambda i, shape: np.random.default_rng(i).random(
+                shape))
         leaves = jax.tree.leaves(reqs[0].program.params)
         assert all(not isinstance(a, np.ndarray) for a in leaves)
         L = len(leaves)
         P = sum(int(a.size) * 4 for a in leaves)
-        S = FAST.multistart
+        S, D, k = FAST.multistart, reqs[0].x0s.shape[-1], 2
         x, f, feas = ex.solve_requests(reqs)
         assert len(x) == sum(sizes)
         st = ex.stats()
         assert st["requests"] == 2 and st["dispatches"] == 1
-        # per request: x0s, los, his, targets to the device; parameters
-        # and the 8 row fields back for packing; x, f, feasible back.
-        # Once: the packed parameters and rows to the device.
-        assert N_ROW_FIELDS == 8
-        assert st["transfers"] == 2 * (4 + L + 8 + 3) + (L + 8)
+        assert st["host_param_misses"] == 2 and st["host_param_hits"] == 0
         Gp, Rp = ex.last_bucket
         assert (Gp, Rp) == (2, 8)
-        want = 0
-        for B in sizes:
-            want += (B * S * D + 2 * B * k + B) * 4          # materialize
-            want += P + (B * S * D + 6 * B * k + B) * 4      # pack
-            want += B * D * 4 + B * k * 4 + B                # unpack
-        want += Gp * P + Gp * Rp * (S * D + 6 * k + 1) * 4   # launch
-        assert st["transfer_bytes"] == want
+        # per dispatch: the packed parameters and 8 row fields to the
+        # device, then x, f and feasible back
+        assert N_ROW_FIELDS == 8
+        per_dispatch = (L + 8) + 3
+        launch_bytes = Gp * P + Gp * Rp * (S * D + 6 * k + 1) * 4
+        unpack_bytes = Gp * Rp * ((D + k) * 4 + 1)
+        # per program, once: its L parameter leaves read back
+        assert st["transfers"] == 2 * L + per_dispatch
+        assert st["transfer_bytes"] == 2 * P + launch_bytes + unpack_bytes
+
+        x2, f2, feas2 = ex.solve_requests(reqs)
+        st2 = ex.stats()
+        assert st2["host_param_misses"] == 2 and st2["host_param_hits"] == 2
+        assert st2["transfers"] - st["transfers"] == per_dispatch
+        assert (st2["transfer_bytes"] - st["transfer_bytes"]
+                == launch_bytes + unpack_bytes)
+        np.testing.assert_array_equal(x2, x)
+        np.testing.assert_array_equal(f2, f)
+        np.testing.assert_array_equal(feas2, feas)
+
+    def test_device_starts_are_read_back_once_per_request(self):
+        """Starts drawn on the device (as ``solve_grouped`` draws them)
+        cost one read-back per request, at their float32 size."""
+        ex = ProbeExecutor(mesh=None)
+        sizes = (3, 5)
+        reqs = self._requests(
+            ex, sizes, lambda i, shape: jax.random.uniform(
+                jax.random.PRNGKey(i), shape))
+        leaves = jax.tree.leaves(reqs[0].program.params)
+        L = len(leaves)
+        P = sum(int(a.size) * 4 for a in leaves)
+        S, D, k = FAST.multistart, reqs[0].x0s.shape[-1], 2
+        ex.solve_requests(reqs)
+        st = ex.stats()
+        Gp, Rp = ex.last_bucket
+        assert st["transfers"] == 2 + 2 * L + (L + 8) + 3
+        assert st["transfer_bytes"] == (
+            sum(B * S * D * 4 for B in sizes) + 2 * P
+            + Gp * P + Gp * Rp * (S * D + 6 * k + 1) * 4
+            + Gp * Rp * ((D + k) * 4 + 1))
 
 
 # -- end-to-end: request-path trace + latency attribution ------------------
