@@ -14,9 +14,16 @@ all points ever offered (under minimization, with near-duplicates deduped
 at 1e-9 resolution like the seed's finalize).  ``finalize`` is therefore a
 plain read — no re-filtering.
 
-Shapes are kept jit-stable: the backing arrays live at power-of-two
-capacity and incoming batches are padded to power-of-two buckets, so a PF
-session triggers only O(log N) compilations of the dominance pass.
+The pass runs on the host.  A served session's frontier holds tens of
+points and an offer batch at most 16 rows, so the comparison is a few
+thousand element pairs: numpy does it in microseconds, while a device
+pass pays four uploads, a dispatch and two read-backs.  So ``add`` runs
+``_host_pass``, the numpy twin of the jitted ``_incremental_pass``, which
+compares at the dtype the device sees and makes the same decisions bit
+for bit; ``_incremental_pass`` stays as its reference.  ``host_passes``
+counts the passes; the ``use_kernel`` store runs the Pallas kernel
+instead, on padded power-of-two shapes (capacity and offer bucket), so a
+session compiles it only O(log N) times.
 """
 
 from __future__ import annotations
@@ -26,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import NOOP_SPAN
+
+from .pareto import compare_dtype, dominated_by
 
 Array = jax.Array
 
@@ -58,6 +67,26 @@ def _incremental_pass(E: Array, alive: Array, B: Array, bvalid: Array):
     ltk = jnp.any(Bk[None, :, :] < Ei[:, None, :], axis=-1)
     killed = jnp.any(jnp.logical_and(lek, ltk), axis=1)
     return keep, jnp.logical_and(alive, ~killed)
+
+
+def _host_pass(E: np.ndarray, alive: np.ndarray, B: np.ndarray,
+               bvalid: np.ndarray):
+    """Numpy twin of :func:`_incremental_pass`: the same arguments, the
+    same decisions, bit for bit.  It compares at the device's dtype
+    (``pareto.compare_dtype``) and only the live rows and valid offers:
+    dead rows and padding are ``+inf`` on the device, which dominates
+    nothing and is never kept."""
+    dt = compare_dtype()
+    live, valid = np.flatnonzero(alive), np.flatnonzero(bvalid)
+    El, Bv = E[live].astype(dt), B[valid].astype(dt)
+    # (1) new vs live frontier and (2) new vs new
+    kv = ~(dominated_by(Bv, El) | dominated_by(Bv, Bv))
+    keep = np.zeros(len(bvalid), dtype=bool)
+    keep[valid[kv]] = True
+    # (3) surviving new points retire the live points they dominate
+    still_alive = np.zeros(len(alive), dtype=bool)
+    still_alive[live[~dominated_by(El, Bv[kv])]] = True
+    return keep, still_alive
 
 
 def _bucket(n: int, floor: int = 4) -> int:
@@ -102,6 +131,8 @@ class FrontierStore:
         self.total_offered = 0
         self.total_accepted = 0
         self.total_infeasible = 0  # offers excluded by the value constraints
+        # dominance passes run in host numpy; not persisted (state_dict)
+        self.host_passes = 0
 
     # ------------------------------------------------------------------
     # Durable export/import (repro.persist, DESIGN.md §13): the state
@@ -281,14 +312,16 @@ class FrontierStore:
         Bp[: len(Fb)] = Fb
         bvalid = np.zeros(bb, dtype=bool)
         bvalid[: len(Fb)] = True
+        path = "kernel" if self.use_kernel else "host"
         with span.child("store.dominance", cat="store",
-                        args={"offers": len(Fb), "kernel": self.use_kernel}):
+                        args={"offers": len(Fb), "kernel": self.use_kernel,
+                              "path": path}):
             if self.use_kernel:
                 keep, still_alive = self._kernel_pass(Bp, bvalid)
             else:
-                keep, still_alive = _incremental_pass(
-                    jnp.asarray(self._F), jnp.asarray(self._alive),
-                    jnp.asarray(Bp), jnp.asarray(bvalid))
+                keep, still_alive = _host_pass(self._F, self._alive, Bp,
+                                               bvalid)
+                self.host_passes += 1
             keep = np.asarray(keep)[: len(Fb)]
             still_alive = np.asarray(still_alive).copy()
         for r in np.nonzero(self._alive & ~still_alive)[0]:

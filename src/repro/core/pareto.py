@@ -4,6 +4,11 @@ All functions operate on arrays of objective-space points with shape
 ``(N, k)`` under *minimization* semantics.  The O(N^2) masked comparison is
 exactly what the Pallas ``pareto_filter`` kernel tiles for TPU; the jnp
 implementation here doubles as its oracle (see ``repro.kernels.pareto_filter``).
+
+The frontier store's pass and the hypervolume's mask run on the host
+instead (DESIGN.md §3): ``dominated_by`` is the numpy twin of the
+dominance predicate, compared at the dtype the device programs see, so
+either side makes the same decisions bit for bit.
 """
 
 from __future__ import annotations
@@ -39,6 +44,33 @@ def pareto_mask(points: Array) -> Array:
     return ~jnp.any(dom, axis=1)
 
 
+def compare_dtype():
+    """The float dtype the device programs compare at: ``jnp.asarray`` of
+    a float64 array gives float32 unless x64 is enabled.  Host twins cast
+    to it, so both sides decide on the same values."""
+    return jax.dtypes.canonicalize_dtype(np.float64)
+
+
+def dominated_by(P: np.ndarray, Q: np.ndarray) -> np.ndarray:
+    """Host twin of the dominance predicate: ``(rows,)`` mask of the rows
+    of ``P: (rows, k)`` that some row of ``Q: (cols, k)`` dominates (Def
+    3.1), compared in the dtype they are given."""
+    le = np.ones((len(P), len(Q)), dtype=bool)
+    lt = np.zeros((len(P), len(Q)), dtype=bool)
+    for c in range(P.shape[1]):
+        p, q = P[:, c, None], Q[None, :, c]
+        le &= q <= p
+        lt |= q < p
+    return np.any(le & lt, axis=1)
+
+
+def _mask(pts: np.ndarray) -> np.ndarray:
+    """``pareto_mask`` of float64 ``pts``, computed in host numpy at the
+    device's dtype: the same mask, bit for bit, without a round trip."""
+    q = pts.astype(compare_dtype())
+    return ~dominated_by(q, q)
+
+
 def pareto_filter(points: Array, payload: Array | None = None):
     """Return the Pareto subset of points (and aligned payload rows)."""
     mask = np.asarray(pareto_mask(jnp.asarray(points)))
@@ -67,8 +99,7 @@ def hypervolume_2d(points: Array, ref: Array) -> float:
     pts = pts[np.all(pts <= ref, axis=1)]
     if len(pts) == 0:
         return 0.0
-    mask = np.asarray(pareto_mask(jnp.asarray(pts)))
-    pts = pts[mask]
+    pts = pts[_mask(pts)]
     order = np.argsort(pts[:, 0])
     pts = pts[order]
     hv, prev_y = 0.0, ref[1]
@@ -95,8 +126,7 @@ def hypervolume(points: Array, ref: Array) -> float:
         pts = pts[np.all(pts <= ref, axis=1)]
         if len(pts) == 0:
             return 0.0
-        mask = np.asarray(pareto_mask(jnp.asarray(pts)))
-        pts = pts[mask]
+        pts = pts[_mask(pts)]
         zs = np.unique(pts[:, 2])
         hv, prev_z = 0.0, ref[2]
         for z in zs[::-1]:

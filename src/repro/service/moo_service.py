@@ -1320,6 +1320,11 @@ class MOOService:
                 "total_probes": sum(
                     s.state.probes for s in self._sessions.values()
                     if s.state is not None),
+                # dominance passes the sessions' frontier stores ran in
+                # host numpy (DESIGN.md §3)
+                "host_passes": sum(
+                    s.state.store.host_passes
+                    for s in self._sessions.values() if s.state is not None),
                 # serving-plane telemetry (DESIGN.md §12): rectangles
                 # still queued across sessions, sessions with pending
                 # work, and probe rows currently solving with the

@@ -501,6 +501,15 @@ class TestServingTrace:
         assert parents_of("pf.absorb") == {"service.absorb"}
         assert parents_of("store.dominance") <= {"pf.absorb"}
         assert "pf.absorb" in parents_of("store.dominance")
+        # each pass says where it ran (host numpy: these stores run no
+        # kernel), and stats() sums the sessions' store counters
+        dom = [s for s in spans.values() if s.name == "store.dominance"]
+        assert {s.args["path"] for s in dom} == {"host"}
+        assert all(s.args["offers"] >= 1 for s in dom)
+        st = svc.stats()
+        stores = [s.state.store for s in svc._sessions.values()]
+        assert st["host_passes"] == sum(s.host_passes for s in stores)
+        assert st["host_passes"] >= len(dom) > 0
         # each served dispatch's phases sit inside it, one of each
         for d in (s for s in spans.values() if s.name == "exec.dispatch"
                   and s.parent_id in spans):

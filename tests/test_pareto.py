@@ -177,3 +177,80 @@ class TestCrowding:
         pts = np.array([[0.0, 5.0], [1.0, 5.0], [2.0, 5.0], [3.0, 5.0]])
         got = crowding_distance(pts)
         np.testing.assert_allclose(got, _crowding_reference(pts))
+
+
+# -- the host twin: small masks run in numpy, bit for bit -------------------
+
+def _twin_points(n: int, k: int, seed: int) -> np.ndarray:
+    """Points with exact ties (a coarse grid) and pairs that are distinct
+    in float64 but equal in float32 (values in [1, 2], where 1e-12 is below
+    float32's resolution)."""
+    rng = np.random.default_rng(seed)
+    pts = 1.0 + rng.integers(0, 9, (n, k)) / 8.0 + rng.uniform(0, 1e-3,
+                                                          (n, k))
+    if n >= 4:
+        pts[1] = pts[0]
+        pts[3] = pts[2] + 1e-12
+    return pts
+
+
+@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("n", [1, 2, 5, 64, 256])
+def test_host_mask_and_hypervolume_match_the_device(n, k, monkeypatch):
+    from repro.core import pareto
+
+    pts = _twin_points(n, k, seed=10 * n + k)
+    q = pts.astype(pareto.compare_dtype())
+    host = ~pareto.dominated_by(q, q)
+    assert host.dtype == bool
+    assert np.array_equal(host, np.asarray(pareto_mask(jnp.asarray(pts))))
+    ref = np.full(k, 2.5)
+    hv = {"host": (hypervolume(pts, ref),
+                   hypervolume_2d(pts[:, :2], ref[:2]))}
+    monkeypatch.setattr(pareto, "_mask", lambda p: np.asarray(
+        pareto_mask(jnp.asarray(p))))
+    hv["device"] = (hypervolume(pts, ref),
+                    hypervolume_2d(pts[:, :2], ref[:2]))
+    assert hv["host"] == hv["device"]  # identical floats, not approx
+    assert hv["host"][0] > 0.0
+
+
+def test_small_absorb_sends_nothing_to_the_device(monkeypatch):
+    """One store pass of 16 offers against 64 live rows and the gain
+    telemetry's hypervolume run with every transfer to or from the device
+    forbidden, and with both device programs replaced by a trap: the
+    small path is host numpy end to end."""
+    import jax
+
+    from repro.core import (FrontierStore, PFState, RectangleQueue,
+                            make_rectangle, pareto)
+    from repro.core import frontier_store
+    from repro.core.progressive_frontier import frontier_hypervolume
+
+    rng = np.random.default_rng(0)
+    a = np.sort(rng.uniform(0, 1, 64))
+    store = FrontierStore(k=2, dim=3)
+    store.add(np.stack([a, 1.0 - a], 1), rng.uniform(0, 1, (64, 3)))
+    assert store.n_points == 64
+    state = PFState(queue=RectangleQueue(make_rectangle(np.zeros(2),
+                                                        np.ones(2))),
+                    store=store, utopia=np.zeros(2), nadir=np.ones(2),
+                    bounds=np.array([[0.0, 0.0], [1.0, 1.0]]))
+    device_pass = frontier_store._incremental_pass
+
+    def trap(*args, **kw):
+        raise AssertionError("a device program ran on the small path")
+
+    monkeypatch.setattr(frontier_store, "_incremental_pass", trap)
+    monkeypatch.setattr(pareto, "pareto_mask", trap)
+    F = rng.uniform(0, 1, (16, 2))
+    with jax.transfer_guard("disallow"):
+        store.add(F, rng.uniform(0, 1, (16, 3)))
+        hv = frontier_hypervolume(state)
+    assert store.host_passes == 2
+    assert 0.0 < hv < 1.0
+    # the guard is live: the device pass on the same numpy arrays is refused
+    with jax.transfer_guard("disallow"), pytest.raises(
+            jax.errors.JaxRuntimeError, match="Disallowed"):
+        device_pass(store._F, store._alive, np.ones((16, 2)),
+                    np.ones(16, bool))
